@@ -55,9 +55,7 @@ def run_table(method: str, *, rows=539, cols=17_088, density=2e-3,
     only up to rotation, which would contaminate e_u with basis
     ambiguity rather than algorithmic error (see EXPERIMENTS.md).
     """
-    from repro.compat import enable_x64  # context-manager config API
-
-    coo = sparse.ensure_full_row_rank(
+    coo =sparse.ensure_full_row_rank(
         sparse.random_bipartite(rows, cols, density, seed=seed,
                                 weighted=weighted), seed=seed)
     a0 = coo.todense()
@@ -66,7 +64,7 @@ def run_table(method: str, *, rows=539, cols=17_088, density=2e-3,
         a = sparse.pad_to_block_multiple(a0, d).astype(np.float64)
         key = jax.random.PRNGKey(seed + d)
         t0 = time.perf_counter()
-        with enable_x64():
+        with jax.enable_x64(True):
             repaired = repaired_matrix(a, d, method, key)
             # exact truth on the repaired matrix (f64)
             u_true, s_true, _ = np.linalg.svd(repaired, full_matrices=False)

@@ -29,8 +29,6 @@ from repro.core import ranky, sparse
 
 def run(rows=539, cols=17_088, density=4e-4, blocks=(8, 32), seed=2021,
         verbose=True):
-    from repro.compat import enable_x64  # context-manager config API
-
     out = []
     coo = sparse.ensure_full_row_rank(
         sparse.random_bipartite(rows, cols, density, seed=seed,
@@ -41,7 +39,7 @@ def run(rows=539, cols=17_088, density=4e-4, blocks=(8, 32), seed=2021,
         for method in ("none", "random", "neighbor", "neighbor_random"):
             key = jax.random.PRNGKey(seed + d)
             t0 = time.perf_counter()
-            with enable_x64():
+            with jax.enable_x64(True):
                 repaired = repaired_matrix(a, d, method, key)
                 u_true, s_true, _ = np.linalg.svd(repaired,
                                                   full_matrices=False)

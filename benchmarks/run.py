@@ -191,6 +191,9 @@ def _timed_section(section: str, rows, full: bool):
 
 
 def main() -> None:
+    from repro import compile_cache
+
+    compile_cache.enable()
     argv = sys.argv[1:]
     full = "--full" in argv
     skip = {"lm"} if "--skip-lm" in argv else set()

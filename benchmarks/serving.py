@@ -52,9 +52,10 @@ def _deltas(n, num_batches, rows, density, seed):
 
 
 def _fused_oracle_match(snapshot, queries_scaled, k_top):
-    """Run the REAL kernel body (interpret mode) on a slice of the live
-    factors vs the oracle — bit-identical or the benchmark fails its
-    gate.  A slice keeps interpret-mode emulation tractable at any N."""
+    """Run the REAL kernel body on a slice of the live factors vs the
+    oracle — bit-identical or the benchmark fails its gate.  On a TPU the
+    kernel is compiled; elsewhere it runs in interpret mode, where the
+    slice keeps emulation tractable at any N."""
     n_slice = min(snapshot.v.shape[0], 4 * BLOCK_N)
     v = snapshot.v[:n_slice]
     valid = min(snapshot.n, n_slice)
@@ -65,7 +66,8 @@ def _fused_oracle_match(snapshot, queries_scaled, k_top):
     got = tks.topk_score(
         jax.numpy.asarray(qs_pad), jax.numpy.asarray(v_pad),
         jax.numpy.ones((n_slice, 1), jax.numpy.float32),
-        valid, 0, k_top=k_top, block_n=BLOCK_N, interpret=True)
+        valid, 0, k_top=k_top, block_n=BLOCK_N,
+        interpret=jax.default_backend() != "tpu")
     want = kref.topk_score(jax.numpy.asarray(qs_pad),
                            jax.numpy.asarray(v_pad), k_top, valid_n=valid)
     return int(np.array_equal(np.asarray(got[0]), np.asarray(want[0]))

@@ -1,43 +1,35 @@
-"""Version-portability shims for jax APIs that moved between releases.
+"""The few JAX entry points the package wraps, in one place.
 
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``, renaming its replication-check kwarg
-  ``check_rep`` -> ``check_vma`` along the way.
-* ``jax.lax.axis_size`` is new; older releases use the classic
-  ``psum(1, axis)`` idiom.
+* ``shard_map_nocheck`` — ``jax.shard_map`` with the replication check
+  off.
+* ``make_mesh`` — ``jax.make_mesh`` with Auto axis types.
+* ``trace_state_clean`` — True in eager code, False while a JAX
+  transformation (jit, scan, shard_map, ...) is tracing.
 """
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.6
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-    _CHECK_KW = "check_rep"
-
 
 def shard_map_nocheck(fn, *, mesh, in_specs, out_specs):
     """shard_map with replication checking off (merge collectives produce
-    replicated outputs the static checker can't see), portable across the
-    check_rep -> check_vma rename."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
+    replicated outputs the static checker can't see)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def axis_size(ax: str):
-    """Size of a named mesh axis from inside a shard_map/pmap region."""
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.6
-        return jax.lax.axis_size(ax)
-    return jax.lax.psum(1, ax)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types.  The default became
+    Explicit, whose arrays carry their mesh in the type and then refuse
+    any op that meets an array placed on another device set (a
+    single-device oracle, a re-meshed survivor pool)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names),
+                         devices=devices)
 
 
-def enable_x64():
-    """Context manager enabling 64-bit mode (jax.enable_x64 is the new
-    name of jax.experimental.enable_x64)."""
-    if hasattr(jax, "enable_x64"):  # jax >= 0.6
-        return jax.enable_x64(True)
-    from jax.experimental import enable_x64 as _enable_x64  # type: ignore
-
-    return _enable_x64(True)
+def trace_state_clean() -> bool:
+    """True when no JAX transformation is tracing: host-side effects
+    (spans, fault seams) fire only then, never into a traced program."""
+    return jax.core.trace_ctx.is_top_level()

@@ -34,14 +34,13 @@ from repro.core import randomized
 from repro.core import ranky
 from repro.core import sparse
 
-from repro.compat import axis_size as _one_axis_size
 from repro.compat import shard_map_nocheck as shard_map
 
 
 def _axis_size(axes: Sequence[str]) -> jnp.ndarray:
     sz = 1
     for ax in axes:
-        sz = sz * _one_axis_size(ax)
+        sz = sz * jax.lax.axis_size(ax)
     return sz
 
 
@@ -49,7 +48,7 @@ def _flat_index(axes: Sequence[str]) -> jnp.ndarray:
     """Row-major flat device index across the given mesh axes."""
     idx = jnp.zeros((), jnp.int32)
     for ax in axes:
-        idx = idx * _one_axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 

@@ -34,6 +34,26 @@ def _mode() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
+# Widths beyond which a kernel no longer fits the v5e's 16 MiB of scoped
+# VMEM (found by compiling for a described v5e; the next width up is
+# refused).  The ELL kernels unroll K slots (the largest column degree of
+# a block) into (rows, block_c) temporaries; blockgram keeps one (M, M)
+# accumulator.  A call past a limit raises instead of quietly routing to
+# XLA.
+MAX_ELL_SLOTS = {"sparse_gram": 128, "sketch_panel": 512}
+MAX_BLOCKGRAM_ROWS = 1024
+
+
+def _check_slots(kernel: str, slots: int) -> None:
+    limit = MAX_ELL_SLOTS[kernel]
+    if slots > limit:
+        raise ValueError(
+            f"{kernel}: a column with {slots} stored slots exceeds the "
+            f"kernel limit MAX_ELL_SLOTS[{kernel!r}]={limit} (K, the "
+            f"largest column degree of a block); run this input with "
+            f"use_kernel=False")
+
+
 def _pad_axis(x: jnp.ndarray, axis: int, multiple: int) -> Tuple[jnp.ndarray, int]:
     size = x.shape[axis]
     pad = (-size) % multiple
@@ -51,6 +71,11 @@ def blockgram(a_blk: jnp.ndarray, *, block_n: int = 512) -> jnp.ndarray:
     if mode == "ref":
         return _ref.blockgram(a_blk)
     m = a_blk.shape[0]
+    if m > MAX_BLOCKGRAM_ROWS:
+        raise ValueError(
+            f"blockgram: M={m} rows exceed the kernel limit "
+            f"MAX_BLOCKGRAM_ROWS={MAX_BLOCKGRAM_ROWS} (one (M, M) f32 "
+            f"accumulator in VMEM); run this input with use_kernel=False")
     a_pad, pad_m = _pad_axis(a_blk, 0, 8)
     block_n = min(block_n, max(128, a_pad.shape[1]))
     a_pad, _ = _pad_axis(a_pad, 1, block_n)
@@ -75,25 +100,40 @@ def _ell_tiles(
     return rows_t, vals_t, block_c
 
 
+def _gram_tiles(m: int, block_m: int) -> Tuple[int, int]:
+    """(tile, padded M) for an (M, M) output cut into (tile, tile)
+    blocks: one 8-aligned tile when M fits ``block_m``, else the fewest
+    128-aligned tiles of at most ``block_m`` rows."""
+    m8 = -(-m // 8) * 8
+    if m8 <= block_m:
+        return m8, m8
+    tiles = -(-m8 // block_m)
+    tile = -(-m8 // (tiles * 128)) * 128
+    return tile, tiles * tile
+
+
 def sparse_gram(
     col_rows: jnp.ndarray,
     col_vals: jnp.ndarray,
     m: int,
     *,
     block_c: int = 512,
+    block_m: int = 512,
 ) -> jnp.ndarray:
     """G = E @ E^T ((M, M) f32) from one block's padded-ELL arrays
-    (C, K) — see core/sparse.py:BlockEll.  Pads M to the 8-sublane grid,
-    K to 8 sublanes and C to block_c lanes; padding slots carry val 0 so
-    they are inert in both the kernel and the oracle."""
+    (C, K) — see core/sparse.py:BlockEll.  The output is tiled into
+    (block_m, block_m) blocks (M padded to the tile grid), K padded to 8
+    sublanes and C to block_c lanes; padding slots carry val 0 so they
+    are inert in both the kernel and the oracle."""
     mode = _mode()
     if mode == "ref":
         return _ref.sparse_gram(col_rows, col_vals, m)
+    _check_slots("sparse_gram", col_rows.shape[1])
     rows_t, vals_t, block_c = _ell_tiles(col_rows, col_vals, block_c)
-    pad_m = (-m) % 8
-    g = _sg.sparse_gram(rows_t, vals_t, m + pad_m, block_c=block_c,
+    tile, m_pad = _gram_tiles(m, block_m)
+    g = _sg.sparse_gram(rows_t, vals_t, m_pad, block_m=tile, block_c=block_c,
                         interpret=(mode == "interpret"))
-    return g[:m, :m] if pad_m else g
+    return g[:m, :m] if m_pad != m else g
 
 
 def sketch_panel(
@@ -113,6 +153,7 @@ def sketch_panel(
     mode = _mode()
     if mode == "ref":
         return _ref.sketch_panel(omega, col_rows, col_vals)
+    _check_slots("sketch_panel", col_rows.shape[1])
     l, c = omega.shape[0], col_rows.shape[0]
     om = omega.astype(jnp.float32)
     om, _ = _pad_axis(om, 0, 8)

@@ -10,13 +10,17 @@ Layout (ops.py transposes from the container's (C, K) and pads):
   rows (K, C) int32 — row index of slot k of stored column c
   vals (K, C) f32   — value (padding slots carry 0)
 
-Grid streams tiles of ``block_c`` stored columns.  Each step expands its
-(K, block_c) slice into a dense (M, block_c) panel in VMEM with K
-one-hot compares against a row iota (VPU work, K is small), then
-accumulates panel @ panel^T on the MXU — the same epilogue as blockgram,
-but HBM traffic is nnz-proportional: 8 bytes per ELL slot instead of
-4*M bytes per dense column, and the MXU contraction runs over stored
-columns only (C ~ nnz) instead of all W columns.
+The (M, M) output is tiled into (bm, bm) blocks: grid =
+(M/bm, M/bm, C/block_c) with the stored-column axis innermost.  Step
+(i, j, c) expands its (K, block_c) ELL slice twice into dense
+(bm, block_c) panels in VMEM — rows of output tile i and rows of tile j —
+with K one-hot compares against a row iota (VPU work, K is small), then
+accumulates panel_i @ panel_j^T on the MXU into one (bm, bm) f32
+accumulator.  VMEM per step is therefore independent of M: a tall batch
+(M in the thousands) tiles instead of asking for an (M, M) accumulator.
+HBM traffic is nnz-proportional: 8 bytes per ELL slot per output tile
+instead of 4*M bytes per dense column, and the MXU contraction runs over
+stored columns only (C ~ nnz) instead of all W columns.
 
 Duplicate (column, row) slots accumulate additively, matching the
 ref.py scatter-add oracle.
@@ -24,6 +28,7 @@ ref.py scatter-add oracle.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,56 +37,70 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _sparse_gram_kernel(rows_ref, vals_ref, out_ref, acc_ref, *, slots):
-    """One grid step: expand an ELL tile to a VMEM panel, acc += P P^T."""
-    i = pl.program_id(0)
+    """One grid step: expand an ELL tile against output row tiles i and
+    j, acc += P_i P_j^T; flush after the last stored-column tile."""
+    i, j, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    @pl.when(i == 0)
+    @pl.when(c == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    m = acc_ref.shape[0]
+    bm = acc_ref.shape[0]
     block_c = rows_ref.shape[1]
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (m, block_c), 0)
-    panel = jnp.zeros((m, block_c), jnp.float32)
-    for k in range(slots):  # static unroll; K is small (max column degree)
-        panel += jnp.where(rows_ref[k:k + 1, :] == row_iota,
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (bm, block_c), 0)
+
+    def panel(tile):
+        p = jnp.zeros((bm, block_c), jnp.float32)
+        for k in range(slots):  # static unroll; K is small (max column degree)
+            p += jnp.where(rows_ref[k:k + 1, :] - tile * bm == row_iota,
                            vals_ref[k:k + 1, :], 0.0)
+        return p
+
     acc_ref[...] += jax.lax.dot_general(
-        panel,
-        panel,
-        (((1,), (1,)), ((), ())),  # contract stored columns: P @ P^T
+        panel(i),
+        panel(j),
+        (((1,), (1,)), ((), ())),  # contract stored columns: P_i @ P_j^T
         preferred_element_type=jnp.float32,
     )
 
-    @pl.when(i == pl.num_programs(0) - 1)
+    @pl.when(c == pl.num_programs(2) - 1)
     def _flush():
         out_ref[...] = acc_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("m", "block_c", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("m", "block_m", "block_c", "interpret"))
 def sparse_gram(
     rows: jnp.ndarray,
     vals: jnp.ndarray,
     m: int,
     *,
+    block_m: Optional[int] = None,
     block_c: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """G = E @ E^T via the Pallas kernel.  Requires M % 8 == 0,
-    C % block_c == 0 and K % 8 == 0 (ops.py pads; val-0 slots are inert)."""
+    """G = E @ E^T via the Pallas kernel, (m, m) in (block_m, block_m)
+    tiles (one tile when block_m is None).  Requires m % block_m == 0,
+    C % block_c == 0 and K % 8 == 0 (ops.py pads; val-0 slots are
+    inert)."""
     k, c = rows.shape
+    block_m = m if block_m is None else block_m
     if c % block_c:
         raise ValueError(f"C={c} must divide block_c={block_c}")
-    grid = (c // block_c,)
+    if m % block_m:
+        raise ValueError(f"M={m} must divide block_m={block_m}")
+    tiles = m // block_m
     return pl.pallas_call(
         functools.partial(_sparse_gram_kernel, slots=k),
-        grid=grid,
+        grid=(tiles, tiles, c // block_c),
         in_specs=[
-            pl.BlockSpec((k, block_c), lambda i: (0, i)),
-            pl.BlockSpec((k, block_c), lambda i: (0, i)),
+            pl.BlockSpec((k, block_c), lambda i, j, s: (0, s)),
+            pl.BlockSpec((k, block_c), lambda i, j, s: (0, s)),
         ],
-        out_specs=pl.BlockSpec((m, m), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((block_m, block_m), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m, m), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_m, block_m), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(rows, vals)

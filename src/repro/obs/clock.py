@@ -15,6 +15,7 @@ from the steady-state execution (``run_time_s``).
 """
 from __future__ import annotations
 
+import threading
 import time
 
 _EPOCH = time.perf_counter()
@@ -40,13 +41,31 @@ def wall() -> float:
 # Compile-time probe (jax.monitoring duration events)
 # ---------------------------------------------------------------------------
 
-_COMPILE = {"secs": 0.0, "installed": False}
+_COMPILE = {"secs": 0.0, "installed": False, "spans": []}
+_COMPILE_LOCK = threading.Lock()
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/"
+# Recent counted (start, end) spans; an event reaches the listener when
+# it ends, so only recent spans can lie inside a new one.  The slack
+# absorbs the listener's own latency in placing a span's start.
+_MAX_SPANS = 256
+_NEST_SLACK_S = 1e-3
 
 
 def _on_event_duration(event: str, secs: float, **_kw) -> None:
-    if event.startswith(_COMPILE_EVENT_PREFIX):
-        _COMPILE["secs"] += secs
+    """Count each compile event once: tracing a jitted function traces
+    the jitted functions it calls, and each reports its own event,
+    nested inside the outer one — those seconds are already counted."""
+    if not event.startswith(_COMPILE_EVENT_PREFIX):
+        return
+    end = now()
+    start = end - secs
+    with _COMPILE_LOCK:
+        spans = _COMPILE["spans"]
+        inner = [sp for sp in spans
+                 if sp[0] >= start - _NEST_SLACK_S and sp[1] <= end]
+        _COMPILE["secs"] += secs - sum(e - s for s, e in inner)
+        spans[:] = [sp for sp in spans if sp not in inner][-_MAX_SPANS:]
+        spans.append((start, end))
 
 
 def install_compile_probe() -> bool:

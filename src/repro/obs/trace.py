@@ -13,7 +13,7 @@ Recording discipline:
 * everything is gated on :func:`repro.obs.gate.enabled` — a disabled
   span is one boolean check and an empty ``yield``;
 * spans never record while jax is tracing
-  (``jax.core.trace_state_clean()``): a span inside a scanned/jitted
+  (``repro.compat.trace_state_clean()``): a span inside a scanned/jitted
   step body would otherwise log trace-time, not run-time.  This makes
   ``span`` safe to place in code that runs both eagerly and under jit
   (e.g. ``hierarchy.merge_svd``);
@@ -117,11 +117,8 @@ def _depth_stack() -> list:
 def _recording() -> bool:
     if not gate.enabled():
         return False
-    try:
-        import jax
-        return jax.core.trace_state_clean()
-    except Exception:   # pragma: no cover - jax internals moved
-        return True
+    from repro.compat import trace_state_clean
+    return trace_state_clean()
 
 
 def _norm_args(kw: Dict[str, object]) -> Tuple[Tuple[str, object], ...]:

@@ -73,6 +73,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.compat import shard_map_nocheck as shard_map
+from repro.compat import trace_state_clean
 from repro.core import hierarchy, randomized, ranky, sparse
 from repro.core import svd as lsvd
 from repro.stream import state as stream_state
@@ -102,7 +103,7 @@ def install_fault_seam(fn) -> None:
 
 
 def _fire_seam(phase: str) -> None:
-    if _fault_seam is not None and jax.core.trace_state_clean():
+    if _fault_seam is not None and trace_state_clean():
         _fault_seam(phase)
 
 

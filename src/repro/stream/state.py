@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.compat import make_mesh
 from repro.core import ranky, sparse
 
 # The one mesh-axis name of the streaming shard_map engine (one column
@@ -194,9 +195,8 @@ def stream_mesh(num_blocks: int, devices=None):
                 f"sharded streaming needs one device per column block: "
                 f"num_blocks={num_blocks} but device_count="
                 f"{jax.device_count()}")
-        return jax.make_mesh((num_blocks,), (STREAM_AXIS,))
-    return jax.make_mesh((num_blocks,), (STREAM_AXIS,),
-                         devices=pool[:num_blocks])
+        return make_mesh((num_blocks,), (STREAM_AXIS,))
+    return make_mesh((num_blocks,), (STREAM_AXIS,), devices=pool[:num_blocks])
 
 
 def shard_state(state: StreamingSVDState, mesh=None) -> StreamingSVDState:

@@ -84,6 +84,50 @@ def test_sparse_gram_ops_padding(monkeypatch):
     assert got.shape == (13, 13)
 
 
+@pytest.mark.parametrize("m,block_m", [(256, 128), (384, 128), (512, 256)])
+def test_sparse_gram_tiled_output(m, block_m):
+    # (block_m, block_m) output tiles, each with its own accumulator over
+    # the stored-column axis, reassemble the oracle's (M, M) gram.
+    rows, vals = _random_ell(m, 256, 8, seed=3)
+    got = sg.sparse_gram(rows.T, vals.T, m, block_m=block_m, block_c=128,
+                         interpret=True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref.sparse_gram(rows, vals, m)),
+                               rtol=2e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("m", [300, 520])
+def test_sparse_gram_ops_tiles_unaligned_m(monkeypatch, m):
+    # M past one tile: ops pads M to the 128-aligned tile grid and trims.
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    rows, vals = _random_ell(m, 200, 5, seed=4)
+    got = ops.sparse_gram(rows, vals, m, block_c=128, block_m=128)
+    assert got.shape == (m, m)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref.sparse_gram(rows, vals, m)),
+                               rtol=2e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("m,block_m,want", [
+    (13, 512, (16, 16)), (544, 512, (384, 768)), (2048, 512, (512, 2048)),
+    (2049, 512, (512, 2560))])
+def test_gram_tiles(m, block_m, want):
+    assert ops._gram_tiles(m, block_m) == want
+
+
+def test_kernel_width_limits_raise(monkeypatch):
+    # Past a kernel's VMEM limit ops raises, naming it, instead of
+    # quietly running XLA.
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    slots = ops.MAX_ELL_SLOTS["sparse_gram"] + 1
+    rows, vals = _random_ell(8, 128, slots)
+    with pytest.raises(ValueError, match="MAX_ELL_SLOTS"):
+        ops.sparse_gram(rows, vals, 8)
+    x = jnp.zeros((ops.MAX_BLOCKGRAM_ROWS + 8, 128), jnp.float32)
+    with pytest.raises(ValueError, match="MAX_BLOCKGRAM_ROWS"):
+        ops.blockgram(x)
+
+
 def test_sparse_gram_matches_dense_blockgram():
     """Container-built ELL gram == dense gram of the same block."""
     from repro.core import sparse as spr

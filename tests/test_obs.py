@@ -322,6 +322,27 @@ def test_diagnostics_compile_run_split():
     assert d1.drift_ratios is None and d1.span_summary is None
 
 
+def test_compile_probe_counts_nested_traces_once():
+    # Tracing a jit traces the jits it calls; each reports its own
+    # trace event, nested inside the caller's.  Counted once, the
+    # compile seconds of a cold call stay within its wall time.
+    from repro.obs import clock
+
+    def body(x):
+        for i in range(300):          # a trace that takes a while
+            x = x * 1.0001 + i
+        return x
+
+    fn = jax.jit(body)
+    for _ in range(6):                # six nested jit levels
+        fn = jax.jit(lambda x, f=fn: f(x) + 1.0)
+    clock.install_compile_probe()
+    c0, t0 = clock.compile_seconds(), clock.now()
+    jax.block_until_ready(fn(jnp.ones((8,), jnp.float32)))
+    wall = clock.now() - t0
+    assert 0 < clock.compile_seconds() - c0 <= wall
+
+
 # ---------------------------------------------------------------------------
 # 8-device shard_map: per-device drift gauges
 # ---------------------------------------------------------------------------
