@@ -14,21 +14,24 @@ One chip runs, through the ``repro.core.api`` front door:
   ``sparse_gram`` kernel and once on XLA.  Reference: numpy's float64
   SVD of the same repaired matrix, the protocol of
   ``benchmarks/paper_tables.py``.
-* **stream** — rows over a MovieLens-25M-shaped item universe (62,423
-  items, ~154 ratings per user; GroupLens ML-25M README), folded in as 8
-  batches of 2,048 rows by ``svd_stream`` with ``truncate_rank=32``, the
-  kernel and the scan window.  Reference: float64 top-32 SVD of the
-  stacked rows.
+* **stream** — user rows over MovieLens-25M's item count (62,423
+  items) at its mean of ~154 ratings per user (GroupLens ML-25M README),
+  folded in as 8 batches of 2,048 rows by ``svd_stream`` with
+  ``truncate_rank=32``, the kernel and the scan window.  The ratings are
+  synthetic: 32 taste groups, items drawn uniformly within a group —
+  MovieLens's popularity skew is not modelled (it would put a batch's
+  largest column degree near 1,000, past the kernel's slot limit).
+  Reference: float64 top-32 SVD of the stacked rows.
 * **serve** — ``serve_init`` / ``serve_topk`` on that state with the
   fused top-k kernel, f32 and int8 factors, a few waves of 64 known-user
   queries.  Reference: float64 top-k over the same (dequantized)
   factors, compared tie-aware.
 
 ``--chips 4`` runs only the paths that exist across chips, at
-``num_blocks=4``: the one-shot under ``backend="shard_map"``, the stream
-under ``stream_backend="shard_map"`` and the ranker under
-``serve_backend="shard_map"``, each beside the same config on the
-single-device engine.
+``num_blocks=4`` and the same sizes: the one-shot under
+``backend="shard_map"``, the stream under ``stream_backend="shard_map"``
+and the ranker under ``serve_backend="shard_map"``, each beside the same
+config on the single-device engine.
 
 Each phase prints its plan, compile and run seconds, errors against the
 reference and the device's peak bytes.  Any failed check raises; the
@@ -89,22 +92,16 @@ SERVE_TOL = 16 * EPS32
 # same phase reached on the CPU backend (XLA, float32) at the same size
 # and seed, measured once; the slack is f32-level.
 STREAM_SLACK = 64 * EPS32
-STREAM_CPU = {"sv_rel": 0.0007613266718892195, "v_sin": 0.006499328908148443}
+STREAM_CPU = {"sv_rel": 0.0010500305442942568, "v_sin": 0.00841367863435033}
 
 PAPER = ranky_paper.config()
 # 160 draws per user leave ~154 distinct ratings once duplicates
-# collapse.  popularity=400 puts every batch's largest column degree in
-# the low twenties, so all eight batches share one ELL bucket (K padded
-# to 32) and the stream compiles one scan window, not one per bucket.
+# collapse.  A batch's largest column degree then lands near 16, on
+# either side of the ELL bucket boundary, so the stream meets more than
+# one bucket shape.
 STREAM_SHAPE = dict(items=62_423, draws_per_row=160, batch_rows=2_048,
-                    batches=8, groups=32, popularity=400.0, seed=25)
+                    batches=8, groups=32, seed=25)
 STREAM_RANK = 32
-# The four-chip stream folds 256-row batches: each distinct stream
-# program holds an eigh of the batch gram, and XLA's TPU eigh of a
-# 2,048-square gram takes six minutes to compile, of a 256-square one
-# seconds.  Two engines, each with a rank-growth program and its
-# windows, would otherwise spend the four-chip run compiling.
-FOUR_CHIP_STREAM_SHAPE = dict(STREAM_SHAPE, batch_rows=256)
 SERVE_SHAPE = dict(batch=64, waves=4, k_top=10, seed=7)
 
 
@@ -128,18 +125,16 @@ _RATING_P = np.array([1, 3, 2, 7, 5, 20, 13, 27, 8, 14], np.float64) / 100
 
 
 def rating_rows(*, items: int, draws_per_row: int, batch_rows: int,
-                batches: int, groups: int, popularity: float,
-                num_blocks: int, seed: int, in_group: float = 0.9):
+                batches: int, groups: int, num_blocks: int, seed: int,
+                in_group: float = 0.9):
     """``batches`` COO batches of user rows over an ``items`` universe.
 
     Each user has a taste group (items are dealt into ``groups`` groups
-    at random) and draws ~``draws_per_row`` ratings, ``in_group`` of them
-    from the group's items and the rest uniformly; duplicates collapse.
-    Within a group the item of popularity rank r is drawn with
-    probability ~ 1 / (r + ``popularity``), a long tail whose offset
-    sets the largest column degree of a batch (the ELL slot count K).
-    Every row gets at least one rating in every column block, so no row
-    is lonely and the stacked rows are already the repaired matrix."""
+    at random) and draws ``draws_per_row`` ratings uniformly, ``in_group``
+    of them from the group's items and the rest from all items;
+    duplicates collapse.  Every row gets at least one rating in every
+    column block, so no row is lonely and the stacked rows are already
+    the repaired matrix."""
     rng = np.random.default_rng(seed)
     r_all = batch_rows * batches
     item_group = rng.permutation(items) % groups
@@ -150,11 +145,7 @@ def rating_rows(*, items: int, draws_per_row: int, batch_rows: int,
 
     rows = np.repeat(np.arange(r_all), draws_per_row)
     g = user_group[rows]
-    n_g = sizes[g]
-    # Inverse CDF of the continuous 1 / (r + popularity) law on [0, n_g).
-    rank = np.floor(popularity * ((1 + n_g / popularity)
-                                  ** rng.random(rows.size) - 1))
-    pick = by_group[starts[g] + np.minimum(rank.astype(np.int64), n_g - 1)]
+    pick = by_group[starts[g] + rng.integers(0, sizes[g])]
     cols = np.where(rng.random(rows.size) < in_group, pick,
                     rng.integers(0, items, rows.size))
     w = sparse.block_width(items, num_blocks)
@@ -216,12 +207,16 @@ def repaired_dense(coo: sparse.COOMatrix, config: api.SolveConfig,
     return a[:, :coo.shape[1]]
 
 
-def oneshot_phase(coo: sparse.COOMatrix, config: api.SolveConfig) -> dict:
+def oneshot_phase(coo: sparse.COOMatrix, config: api.SolveConfig,
+                  reference=None) -> dict:
     """One ``api.svd`` solve of ``coo`` (want_right) against numpy's
-    float64 SVD of the same repaired matrix."""
+    float64 SVD of the same repaired matrix.  ``reference`` is a previous
+    run's ``out["reference"]`` when the repair is the same."""
     res = api.svd(coo, config)
-    a = repaired_dense(coo, config, res.plan.num_blocks)
-    u_ref, s_ref, _ = np.linalg.svd(a, full_matrices=False)
+    if reference is None:
+        a = repaired_dense(coo, config, res.plan.num_blocks)
+        reference = (a, *np.linalg.svd(a, full_matrices=False)[:2])
+    a, u_ref, s_ref = reference
     u = np.asarray(res.u, np.float64)
     s = np.asarray(res.s, np.float64)
     v = np.asarray(res.v, np.float64)
@@ -244,6 +239,7 @@ def oneshot_phase(coo: sparse.COOMatrix, config: api.SolveConfig) -> dict:
             "orth": float(np.max(np.abs(u.T @ u - np.eye(r)))),
         },
         "result": res,
+        "reference": reference,
     }
 
 
@@ -268,13 +264,14 @@ def stream_reference(batches, k: int):
     return s[order], vt[order].T
 
 
-def stream_phase(batches, config: api.SolveConfig) -> dict:
+def stream_phase(batches, config: api.SolveConfig, reference=None) -> dict:
     """``api.svd_stream`` over the batches against the float64 top-k
     SVD of the stacked rows: the top-k singular values and the right
-    subspace."""
+    subspace.  ``reference`` is a previous run's ``out["reference"]``."""
     res = api.svd_stream(batches, config)
-    k = config.truncate_rank
-    s_ref, v_ref = stream_reference(batches, k)
+    if reference is None:
+        reference = stream_reference(batches, config.truncate_rank)
+    s_ref, v_ref = reference
     s = np.asarray(res.s, np.float64)
     v = np.asarray(res.state.trimmed_v(), np.float64)
     return {
@@ -287,6 +284,7 @@ def stream_phase(batches, config: api.SolveConfig) -> dict:
             "v_sin": _subspace_sin(v, v_ref),
         },
         "result": res,
+        "reference": reference,
     }
 
 
@@ -336,9 +334,8 @@ def serve_phase(state, config: api.ServeTopKConfig, *, waves: int,
                     f"the tolerance")
             dev = np.abs(vals[b] - ref_scores[b, idx[b]]) / tol[b]
             worst = max(worst, float(dev.max()))
-        with jax.default_matmul_precision(api.MATMUL_PRECISION):
-            want = kref.topk_score(ranker.fold_queries(snap, q), factors,
-                                   config.k_top, scale=scale, valid_n=snap.n)
+        want = kref.topk_score(ranker.fold_queries(snap, q), factors,
+                               config.k_top, scale=scale, valid_n=snap.n)
         bitwise &= bool(np.array_equal(np.asarray(want[0]),
                                        np.asarray(res.scores))
                         and np.array_equal(np.asarray(want[1]),
@@ -384,7 +381,7 @@ def peak_bytes():
 
 
 def report(name: str, out: dict, bounds: dict) -> None:
-    line = {k: v for k, v in out.items() if k != "result"}
+    line = {k: v for k, v in out.items() if k not in ("result", "reference")}
     line["bounds"] = bounds
     line["peak_bytes_in_use"] = peak_bytes()
     line["elapsed_s"] = clock.now()          # since the process started
@@ -392,8 +389,7 @@ def report(name: str, out: dict, bounds: dict) -> None:
 
 
 def lowered_text(fn, *args) -> str:
-    with jax.default_matmul_precision(api.MATMUL_PRECISION):
-        return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args).compile().as_text()
 
 
 def assert_kernels_lowered(shapes) -> None:
@@ -432,9 +428,12 @@ def one_chip() -> None:
     cfg = api.SolveConfig(method="neighbor_random", num_blocks=8,
                           want_right=True)
     ell = sparse.block_ell_from_coo(coo, 8)
+    ref = None
     for use_kernel in (True, False):
         out = oneshot_phase(coo, dataclasses.replace(cfg,
-                                                     use_kernel=use_kernel))
+                                                     use_kernel=use_kernel),
+                            ref)
+        ref = out["reference"]
         report(f"oneshot use_kernel={use_kernel}", out, ONESHOT_TOL)
         check("oneshot", out, ONESHOT_TOL, backend="single",
               strategy="exact_gram")
@@ -479,9 +478,11 @@ def four_chips(coo: sparse.COOMatrix, batches, *, d: int = 4,
     beside the same config on the single-device engine."""
     cfg = api.SolveConfig(method="neighbor_random", num_blocks=d,
                           want_right=True, use_kernel=True)
-    outs = {}
+    outs, ref = {}, None
     for backend in ("shard_map", "single"):
-        out = oneshot_phase(coo, dataclasses.replace(cfg, backend=backend))
+        out = oneshot_phase(coo, dataclasses.replace(cfg, backend=backend),
+                            ref)
+        ref = out["reference"]
         report(f"oneshot backend={backend}", out, ONESHOT_TOL)
         check("oneshot", out, ONESHOT_TOL, backend=backend,
               strategy="exact_gram")
@@ -496,12 +497,13 @@ def four_chips(coo: sparse.COOMatrix, batches, *, d: int = 4,
 
     scfg = api.SolveConfig(method="neighbor_random", num_blocks=d,
                            truncate_rank=rank, use_kernel=True)
-    outs = {}
+    outs, ref = {}, None
     # The single-device engine first: its error against the reference
     # (the truncation error by design) bounds the sharded engine's.
     for backend in ("single", "shard_map"):
         out = stream_phase(batches, dataclasses.replace(
-            scfg, stream_backend=backend))
+            scfg, stream_backend=backend), ref)
+        ref = out["reference"]
         bounds = ({k: v + STREAM_SLACK
                    for k, v in outs["single"]["errors"].items()}
                   if outs else {})
@@ -557,15 +559,16 @@ def main(argv=None) -> int:
     if jax.default_backend() != "tpu":
         raise SystemExit(f"no TPU: JAX's default backend is "
                          f"{jax.default_backend()!r}")
-    compile_cache.enable()
+    cache_dir = compile_cache.enable()
     if len(jax.devices()) < args.chips:
         raise SystemExit(f"--chips {args.chips} but JAX sees "
                          f"{len(jax.devices())} device(s)")
     if args.chips == 4:
-        four_chips(paper_matrix(),
-                   rating_rows(num_blocks=4, **FOUR_CHIP_STREAM_SHAPE))
+        four_chips(paper_matrix(), rating_rows(num_blocks=4, **STREAM_SHAPE))
     else:
         one_chip()
+    print("compile cache: " + json.dumps(compile_cache.usage(cache_dir)),
+          flush=True)
     dev = jax.devices()[0]
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

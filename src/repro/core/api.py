@@ -45,7 +45,6 @@ Usage::
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 from typing import Any, Dict, Optional, Tuple, Union
 
@@ -74,24 +73,6 @@ _REPAIR_DIAG_MAX_M = 4096
 
 MatrixInput = Union[np.ndarray, jnp.ndarray, "sparse.COOMatrix",
                     "sparse.BlockEll"]
-
-# Precision of every f32 contraction a front-door call traces: the batch
-# and block grams, the sketch passes, the merge Grams and the Pallas
-# kernels' dots.  On a TPU, DEFAULT is one bfloat16 pass (~1e-3 relative
-# error in a gram); "highest" keeps them at float32 accuracy.  CPU
-# results do not depend on it.
-MATMUL_PRECISION = "highest"
-
-
-def _f32_contractions(fn):
-    """Run ``fn`` with JAX's default matmul precision set to
-    :data:`MATMUL_PRECISION` (read per call)."""
-    @functools.wraps(fn)
-    def wrapped(*args, **kwargs):
-        with jax.default_matmul_precision(MATMUL_PRECISION):
-            return fn(*args, **kwargs)
-    return wrapped
-
 
 def _bad(field_a: str, val_a, field_b: str, val_b, why: str,
          kind: str = "SolveConfig") -> ValueError:
@@ -638,7 +619,6 @@ class _CallTimer:
         return out
 
 
-@_f32_contractions
 def svd(a: MatrixInput, config: Optional[SolveConfig] = None, *,
         mesh=None, block_axes=None, **overrides) -> SVDResult:
     """Distributed Ranky SVD of ``a`` — the one public entry point.
@@ -854,7 +834,6 @@ def plan_update(batch: Union[MatrixInput, ASpec],
         f"seen; excluded from the R5 peak)",))
 
 
-@_f32_contractions
 def svd_update(state, delta, config: Optional[SolveConfig] = None,
                **overrides) -> SVDResult:
     """Fold a batch of new rows into an existing streaming state — the
@@ -910,7 +889,6 @@ def svd_update(state, delta, config: Optional[SolveConfig] = None,
                      diagnostics=diag, state=new_state)
 
 
-@_f32_contractions
 def svd_stream(batches, config: Optional[SolveConfig] = None, *,
                state=None, **overrides) -> SVDResult:
     """Ingest a whole sequence of deltas and return the final result.
@@ -1201,7 +1179,6 @@ def serve_init(state, config: Optional[ServeTopKConfig] = None,
                        plan=plan, config=resolved)
 
 
-@_f32_contractions
 def serve_topk(handle: ServeHandle, queries,
                k_top: Optional[int] = None) -> "ranker_mod.TopKResult":
     """Answer one request wave against the handle's CURRENT snapshot.

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 
 from repro.core import sparse
 from repro.core.ranky import default_key
+from repro.precision import mm
 
 # Key fold tag for the test matrix: shared by the single-host and
 # distributed drivers so both draw the identical Omega for a given key.
@@ -76,13 +77,13 @@ def draw_omega(key: jax.Array, l: int, m: int) -> jnp.ndarray:
 
 def sketch_block_dense(omega: jnp.ndarray, blk: jnp.ndarray) -> jnp.ndarray:
     """(L, M) @ (M, W) -> (L, W): the dense-twin sketch of one block."""
-    return omega @ blk.astype(jnp.float32)
+    return mm(omega, blk.astype(jnp.float32))
 
 
 def pullback_block_dense(g: jnp.ndarray, blk: jnp.ndarray) -> jnp.ndarray:
     """(L, W) @ (W, M) -> (L, M): G_d @ B_d^T (summed over blocks by the
     caller — the psum in the distributed driver)."""
-    return g @ blk.astype(jnp.float32).T
+    return mm(g, blk.astype(jnp.float32).T)
 
 
 def sketch_block_sparse(
@@ -155,9 +156,9 @@ def truncate_sketch(
     inv_sqrt = jnp.where(good,
                          1.0 / jnp.sqrt(jnp.where(good, evals, 1.0)), 0.0)
     w = evecs * inv_sqrt[None, :]                     # (L, L)
-    b = t.T @ w                                       # (M, L) = A @ Vtilde
+    b = mm(t.T, w)                                      # (M, L) = A @ Vtilde
     u_b, s, w_bt = jnp.linalg.svd(b, full_matrices=False)
-    return u_b[:, :rank], s[:rank], w @ w_bt.T[:, :rank]
+    return u_b[:, :rank], s[:rank], mm(w, w_bt.T[:, :rank])
 
 
 def _range_finder(
@@ -256,7 +257,7 @@ def block_truncated_panels(
         l = sketch_width(rank, oversample, m)
         omega = draw_omega(key, l, m)
         g, t = _range_finder(sketch1, pullback1, omega, power_iters)
-        u, s, _ = truncate_sketch(t, g @ g.T, rank)
+        u, s, _ = truncate_sketch(t, mm(g, g.T), rank)
         return u * s[None, :]
 
     if isinstance(blocks, sparse.RepairedSparseBlocks):
@@ -311,8 +312,8 @@ def randomized_tail_over(
         return jax.lax.psum(pullback_local(g), axes)
 
     g, t = _range_finder(sketch, pullback, omega, power_iters)
-    h = jax.lax.psum(g @ g.T, axes)
+    h = jax.lax.psum(mm(g, g.T), axes)
     u, s, vproj = truncate_sketch(t, h, rank)
     if not want_right:
         return u, s
-    return u, s, g.T @ vproj
+    return u, s, mm(g.T, vproj)

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import sparse
+from repro.precision import mm
 
 
 def gram(a_blk: jnp.ndarray, *, use_kernel: bool = False) -> jnp.ndarray:
@@ -39,7 +40,7 @@ def gram(a_blk: jnp.ndarray, *, use_kernel: bool = False) -> jnp.ndarray:
         from repro.kernels import ops as kops
 
         return kops.blockgram(a_blk)
-    return a_blk @ a_blk.T
+    return mm(a_blk, a_blk.T)
 
 
 def sparse_gram_block(
@@ -74,11 +75,11 @@ def sparse_gram_block(
 
         g_e = kops.sparse_gram(col_rows, col_vals, m)
     else:
-        g_e = panel.T @ panel
+        g_e = mm(panel.T, panel)
     rmask = repair_mask.astype(jnp.float32)
     match = (col_ids[:, None] == repair_cols[None, :]).astype(jnp.float32) \
         * rmask[None, :]                                     # (C, M)
-    cross = panel.T @ match                                  # (M, M)
+    cross = mm(panel.T, match)                               # (M, M)
     g_r = (repair_cols[:, None] == repair_cols[None, :]).astype(jnp.float32) \
         * (rmask[:, None] * rmask[None, :])
     return g_e + cross + cross.T + g_r
@@ -189,7 +190,7 @@ def right_vectors(
     """
     smax = jnp.max(s)
     inv = jnp.where(s > rcond * smax, 1.0 / jnp.where(s == 0, 1.0, s), 0.0)
-    return (a_blk.T @ u) * inv[None, :]
+    return mm(a_blk.T, u) * inv[None, :]
 
 
 def sparse_right_vectors(
@@ -211,7 +212,7 @@ def sparse_right_vectors(
     truncated merge)."""
     m = u.shape[0]
     panel = sparse.stored_col_panel(col_rows, col_vals, m)   # (C, M)
-    atu = jnp.zeros((width, u.shape[1]), u.dtype).at[col_ids].add(panel @ u)
+    atu = jnp.zeros((width, u.shape[1]), u.dtype).at[col_ids].add(mm(panel, u))
     atu = atu.at[repair_cols].add(repair_mask[:, None] * u)
     smax = jnp.max(s)
     inv = jnp.where(s > rcond * smax, 1.0 / jnp.where(s == 0, 1.0, s), 0.0)

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import precision
+
 
 def _gram_kernel(a_ref, out_ref, acc_ref):
     """One grid step: acc += A_tile @ A_tile^T ; flush on the last tile."""
@@ -35,6 +37,7 @@ def _gram_kernel(a_ref, out_ref, acc_ref):
         tile,
         tile,
         (((1,), (1,)), ((), ())),  # contract the N dimension: A @ A^T
+        precision=precision.MATMUL,
         preferred_element_type=jnp.float32,
     )
 

@@ -38,10 +38,15 @@ def _mode() -> str:
 # VMEM (found by compiling for a described v5e; the next width up is
 # refused).  The ELL kernels unroll K slots (the largest column degree of
 # a block) into (rows, block_c) temporaries; blockgram keeps one (M, M)
-# accumulator.  A call past a limit raises instead of quietly routing to
-# XLA.
+# accumulator.  sparse_gram keeps one (tile, tile) accumulator: its
+# (M, M) output is one block up to MAX_GRAM_BLOCK rows (768 is refused
+# at K=128) and tiles of at most MAX_GRAM_TILE rows beyond (a tile
+# expands two panels).  A call past a limit raises instead of quietly
+# routing to XLA.
 MAX_ELL_SLOTS = {"sparse_gram": 128, "sketch_panel": 512}
 MAX_BLOCKGRAM_ROWS = 1024
+MAX_GRAM_BLOCK = 640
+MAX_GRAM_TILE = 512
 
 
 def _check_slots(kernel: str, slots: int) -> None:
@@ -100,14 +105,14 @@ def _ell_tiles(
     return rows_t, vals_t, block_c
 
 
-def _gram_tiles(m: int, block_m: int) -> Tuple[int, int]:
-    """(tile, padded M) for an (M, M) output cut into (tile, tile)
-    blocks: one 8-aligned tile when M fits ``block_m``, else the fewest
-    128-aligned tiles of at most ``block_m`` rows."""
+def _gram_tiles(m: int) -> Tuple[int, int]:
+    """(tile, padded M) for sparse_gram's (M, M) output: one 8-aligned
+    block up to MAX_GRAM_BLOCK rows, else the fewest 128-aligned tiles
+    of at most MAX_GRAM_TILE rows."""
     m8 = -(-m // 8) * 8
-    if m8 <= block_m:
+    if m8 <= MAX_GRAM_BLOCK:
         return m8, m8
-    tiles = -(-m8 // block_m)
+    tiles = -(-m8 // MAX_GRAM_TILE)
     tile = -(-m8 // (tiles * 128)) * 128
     return tile, tiles * tile
 
@@ -118,19 +123,18 @@ def sparse_gram(
     m: int,
     *,
     block_c: int = 512,
-    block_m: int = 512,
 ) -> jnp.ndarray:
     """G = E @ E^T ((M, M) f32) from one block's padded-ELL arrays
-    (C, K) — see core/sparse.py:BlockEll.  The output is tiled into
-    (block_m, block_m) blocks (M padded to the tile grid), K padded to 8
-    sublanes and C to block_c lanes; padding slots carry val 0 so they
-    are inert in both the kernel and the oracle."""
+    (C, K) — see core/sparse.py:BlockEll.  M pads to the tile grid of
+    :func:`_gram_tiles`, K to 8 sublanes and C to block_c lanes; padding
+    slots carry val 0 so they are inert in both the kernel and the
+    oracle."""
     mode = _mode()
     if mode == "ref":
         return _ref.sparse_gram(col_rows, col_vals, m)
     _check_slots("sparse_gram", col_rows.shape[1])
     rows_t, vals_t, block_c = _ell_tiles(col_rows, col_vals, block_c)
-    tile, m_pad = _gram_tiles(m, block_m)
+    tile, m_pad = _gram_tiles(m)
     g = _sg.sparse_gram(rows_t, vals_t, m_pad, block_m=tile, block_c=block_c,
                         interpret=(mode == "interpret"))
     return g[:m, :m] if m_pad != m else g

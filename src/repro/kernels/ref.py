@@ -10,6 +10,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.precision import mm
+
 
 # ---------------------------------------------------------------------------
 # blockgram: G = A @ A^T for a short-and-fat block (Ranky local gram)
@@ -18,7 +20,7 @@ import jax.numpy as jnp
 def blockgram(a_blk: jnp.ndarray) -> jnp.ndarray:
     """(M, N) -> (M, M) gram in f32 accumulation."""
     a32 = a_blk.astype(jnp.float32)
-    return a32 @ a32.T
+    return mm(a32, a32.T)
 
 
 # ---------------------------------------------------------------------------
@@ -40,7 +42,7 @@ def sparse_gram(
     p = jnp.zeros((c, m), jnp.float32).at[
         jnp.arange(c)[:, None], col_rows
     ].add(col_vals.astype(jnp.float32))
-    return p.T @ p
+    return mm(p.T, p)
 
 
 # ---------------------------------------------------------------------------
@@ -89,7 +91,7 @@ def topk_score(
     ``index_offset`` may be traced scalars (the sharded backend feeds
     per-device offsets).
     """
-    scores = qs.astype(jnp.float32) @ v.astype(jnp.float32).T  # (B, N)
+    scores = mm(qs.astype(jnp.float32), v.astype(jnp.float32).T)  # (B, N)
     if scale is not None:
         scores = scores * scale.astype(jnp.float32)[None, :]
     if valid_n is not None:

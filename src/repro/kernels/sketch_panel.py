@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import precision
+
 
 def _sketch_panel_kernel(omega_ref, rows_ref, vals_ref, out_ref, *, slots):
     """One grid step: expand an ELL tile against one M tile, accumulate."""
@@ -46,6 +48,7 @@ def _sketch_panel_kernel(omega_ref, rows_ref, vals_ref, out_ref, *, slots):
         omega_ref[...],
         panel,
         (((1,), (0,)), ((), ())),  # (L, block_m) @ (block_m, block_c)
+        precision=precision.MATMUL,
         preferred_element_type=jnp.float32,
     )
 

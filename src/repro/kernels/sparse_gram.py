@@ -10,14 +10,18 @@ Layout (ops.py transposes from the container's (C, K) and pads):
   rows (K, C) int32 — row index of slot k of stored column c
   vals (K, C) f32   — value (padding slots carry 0)
 
-The (M, M) output is tiled into (bm, bm) blocks: grid =
-(M/bm, M/bm, C/block_c) with the stored-column axis innermost.  Step
-(i, j, c) expands its (K, block_c) ELL slice twice into dense
-(bm, block_c) panels in VMEM — rows of output tile i and rows of tile j —
-with K one-hot compares against a row iota (VPU work, K is small), then
-accumulates panel_i @ panel_j^T on the MXU into one (bm, bm) f32
-accumulator.  VMEM per step is therefore independent of M: a tall batch
-(M in the thousands) tiles instead of asking for an (M, M) accumulator.
+The (M, M) output is one (bm, bm) block up to bm = M, or tiled beyond
+(ops.py picks the tile).  The gram is symmetric, so only the tiles on
+and above the diagonal are computed: grid = (pairs, C/block_c) over the
+(i <= j) tile pairs, which arrive as scalar-prefetched tile indices,
+with the stored-column axis innermost.  Step (p, c) expands its
+(K, block_c) ELL slice twice into dense (bm, block_c) panels in VMEM —
+rows of output tile i and rows of tile j — with K one-hot compares
+against a row iota (VPU work, K is small), then accumulates
+panel_i @ panel_j^T on the MXU into one (bm, bm) f32 accumulator; the
+lower tiles are mirrored from the upper ones afterwards.  VMEM per step
+is independent of M: a tall batch tiles instead of asking for an
+(M, M) accumulator.
 HBM traffic is nnz-proportional: 8 bytes per ELL slot per output tile
 instead of 4*M bytes per dense column, and the MXU contraction runs over
 stored columns only (C ~ nnz) instead of all W columns.
@@ -30,16 +34,23 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import precision
 
-def _sparse_gram_kernel(rows_ref, vals_ref, out_ref, acc_ref, *, slots):
+
+def _sparse_gram_kernel(ti_ref, tj_ref, rows_ref, vals_ref, out_ref,
+                        acc_ref, *, slots, one_tile):
     """One grid step: expand an ELL tile against output row tiles i and
-    j, acc += P_i P_j^T; flush after the last stored-column tile."""
-    i, j, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    j of tile pair p, acc += P_i P_j^T; flush after the last
+    stored-column tile.  With ``one_tile`` (the whole gram one block)
+    the single panel is expanded once."""
+    p, c = pl.program_id(0), pl.program_id(1)
 
     @pl.when(c == 0)
     def _init():
@@ -50,20 +61,22 @@ def _sparse_gram_kernel(rows_ref, vals_ref, out_ref, acc_ref, *, slots):
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (bm, block_c), 0)
 
     def panel(tile):
-        p = jnp.zeros((bm, block_c), jnp.float32)
+        pan = jnp.zeros((bm, block_c), jnp.float32)
         for k in range(slots):  # static unroll; K is small (max column degree)
-            p += jnp.where(rows_ref[k:k + 1, :] - tile * bm == row_iota,
-                           vals_ref[k:k + 1, :], 0.0)
-        return p
+            pan += jnp.where(rows_ref[k:k + 1, :] - tile * bm == row_iota,
+                             vals_ref[k:k + 1, :], 0.0)
+        return pan
 
+    pan_i = panel(ti_ref[p])
     acc_ref[...] += jax.lax.dot_general(
-        panel(i),
-        panel(j),
+        pan_i,
+        pan_i if one_tile else panel(tj_ref[p]),
         (((1,), (1,)), ((), ())),  # contract stored columns: P_i @ P_j^T
+        precision=precision.MATMUL,
         preferred_element_type=jnp.float32,
     )
 
-    @pl.when(c == pl.num_programs(2) - 1)
+    @pl.when(c == pl.num_programs(1) - 1)
     def _flush():
         out_ref[...] = acc_ref[...]
 
@@ -90,17 +103,27 @@ def sparse_gram(
     if m % block_m:
         raise ValueError(f"M={m} must divide block_m={block_m}")
     tiles = m // block_m
-    return pl.pallas_call(
-        functools.partial(_sparse_gram_kernel, slots=k),
-        grid=(tiles, tiles, c // block_c),
-        in_specs=[
-            pl.BlockSpec((k, block_c), lambda i, j, s: (0, s)),
-            pl.BlockSpec((k, block_c), lambda i, j, s: (0, s)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_m), lambda i, j, s: (i, j)),
+    ti, tj = np.triu_indices(tiles)
+    g = pl.pallas_call(
+        functools.partial(_sparse_gram_kernel, slots=k, one_tile=tiles == 1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(ti.size, c // block_c),
+            in_specs=[
+                pl.BlockSpec((k, block_c), lambda p, s, ti, tj: (0, s)),
+                pl.BlockSpec((k, block_c), lambda p, s, ti, tj: (0, s)),
+            ],
+            out_specs=pl.BlockSpec((block_m, block_m),
+                                   lambda p, s, ti, tj: (ti[p], tj[p])),
+            scratch_shapes=[pltpu.VMEM((block_m, block_m), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_m, block_m), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(rows, vals)
+    )(jnp.asarray(ti, jnp.int32), jnp.asarray(tj, jnp.int32), rows, vals)
+    if tiles == 1:
+        return g
+    # Only the (i <= j) tiles were written: mirror the upper ones down.
+    tile = jnp.arange(m) // block_m
+    return jnp.where(tile[:, None] <= tile[None, :], g, g.T)

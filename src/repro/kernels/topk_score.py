@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import precision
+
 _NEG_INF = float("-inf")
 
 
@@ -81,6 +83,7 @@ def _topk_score_kernel(
     tile = v_ref[...].astype(jnp.float32)                          # (BN, k)
     scores = jax.lax.dot_general(
         qs_ref[...], tile, (((1,), (1,)), ((), ())),
+        precision=precision.MATMUL,
         preferred_element_type=jnp.float32,
     )                                                              # (B, BN)
     scores = scores * scale_ref[...][:, 0][None, :]

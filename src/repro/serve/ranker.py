@@ -36,6 +36,7 @@ from repro import obs
 from repro.compat import shard_map_nocheck as shard_map
 from repro.kernels import ops
 from repro.kernels import ref as _ref
+from repro.precision import mm
 from repro.serve.snapshot import ServingSnapshot
 from repro.stream.state import STREAM_AXIS, stream_mesh
 
@@ -74,11 +75,11 @@ def project_rows(snapshot: ServingSnapshot, rows: jnp.ndarray) -> jnp.ndarray:
         n_pad = snapshot.v_q.shape[0]
         rows = jnp.pad(rows, ((0, 0), (0, n_pad - snapshot.n)))
         scaled = rows * snapshot.v_scale[:, 0][None, :]
-        proj = scaled @ snapshot.v_q.astype(jnp.float32)
+        proj = mm(scaled, snapshot.v_q.astype(jnp.float32))
     else:
         n_pad = snapshot.v.shape[0]
         rows = jnp.pad(rows, ((0, 0), (0, n_pad - snapshot.n)))
-        proj = rows @ snapshot.v
+        proj = mm(rows, snapshot.v)
     return proj / snapshot.s.astype(jnp.float32)[None, :]
 
 

@@ -33,10 +33,16 @@ Nothing in steps 3–4 depends on ``rows_seen``: the merge works on an
 (n_pad, k + r_b) panel and the batch factorization on the batch alone —
 planner rule R5's closed form, ``O(batch + (k+p) * N)`` peak.
 
+The exact batch factorization's eigh is its own program, keyed on the
+batch height alone (:func:`batch_left_vectors`), never fused into an
+engine's step: every engine and window shape reuses one compile per
+batch height.
+
 **Distributed ingestion** (``plan.backend == "shard_map"``, rule R5d):
-the same four steps run inside one ``shard_map`` region over a
-one-block-per-device mesh, and no device ever materializes anything
-N-sized:
+the same four steps run in ``shard_map`` regions over a
+one-block-per-device mesh (the exact path's gram in one region, its
+eigh between, panel and merge in a second), and no device ever
+materializes anything N-sized:
 
 * the state's ``v`` is row-sharded (device d owns its column block's
   (W, k) slice), deltas shard like every other path (dense columns /
@@ -47,7 +53,8 @@ N-sized:
   row adjacency is the psum of binarized local grams (the same matrix
   ``row_adjacency`` computes on one host);
 * the exact batch factorization psums the per-device (m_b, m_b) grams
-  into one eigh; the randomized one runs ``randomized_tail_over`` —
+  into one eigh (on the mesh's first device, the result replicated
+  back); the randomized one runs ``randomized_tail_over`` —
   identical Omega and the same (L, m_b) psum'd pullbacks as the
   distributed one-shot driver;
 * the merge never stacks the (N_pad, k + r_b) panel: each device forms
@@ -76,6 +83,7 @@ from repro.compat import shard_map_nocheck as shard_map
 from repro.compat import trace_state_clean
 from repro.core import hierarchy, randomized, ranky, sparse
 from repro.core import svd as lsvd
+from repro.precision import mm
 from repro.stream import state as stream_state
 from repro.stream.state import STREAM_AXIS, StreamingSVDState
 
@@ -130,6 +138,24 @@ def _repaired_count(blocks, lonely_total: int) -> int:
     return lonely_total - int(np.asarray(still_lonely).sum())
 
 
+def batch_left_vectors(g: jnp.ndarray, r_b: int, mesh=None) -> jnp.ndarray:
+    """U_b (m, r_b): the top ``r_b`` eigenvectors of a repaired batch's
+    summed (m, m) gram — the exact batch factorization.
+
+    The eigh runs as its own program (``lsvd.merge_grams_eigh``) keyed on
+    m alone, never inside an engine's region or scan: the per-batch and
+    windowed engines, single-host or sharded, share one compile per batch
+    height, whatever the batch's ELL bucket or window length.  On a TPU
+    that compile dominates (XLA's eigh of a 2,048-square gram emits
+    ~600 MB of code).  With ``mesh``, ``g`` is replicated over it: the
+    eigh runs on the mesh's first device and U_b comes back replicated."""
+    if mesh is None:
+        return lsvd.merge_grams_eigh(g)[0][:, :r_b]
+    g0 = jax.device_put(g, mesh.devices.flat[0])
+    return jax.device_put(lsvd.merge_grams_eigh(g0)[0][:, :r_b],
+                          NamedSharding(mesh, P()))
+
+
 def _factor_batch(blocks, m_b: int, config, plan, k_batch: jax.Array):
     """(U_b (m_b, r_b), P_b (n_pad, r_b)) of the repaired batch, per the
     plan's R5 strategy.  ``P_b = B^T U_b`` exactly — the batch's
@@ -137,12 +163,12 @@ def _factor_batch(blocks, m_b: int, config, plan, k_batch: jax.Array):
     implicitly and formed without dividing by them (so rank-deficient
     batches stay finite)."""
     if plan.rank is None:
-        # Exact: per-block gram stack (sparse-native E+R grams) + eigh,
-        # truncated to the merge width r_b = min(m_b, k + oversample).
-        u_b, _ = lsvd.merge_grams_eigh(
-            lsvd.gram_stack(blocks, use_kernel=config.use_kernel))
+        # Exact: summed per-block gram stack (sparse-native E+R grams) +
+        # eigh, truncated to the merge width r_b = min(m_b, k + oversample).
         r_b = min(m_b, config.truncate_rank + config.oversample)
-        u_b = u_b[:, :r_b]
+        u_b = batch_left_vectors(
+            lsvd.gram_stack(blocks, use_kernel=config.use_kernel).sum(axis=0),
+            r_b)
         panel_b = ranky.right_vectors_stack(
             blocks, u_b, jnp.ones((r_b,), jnp.float32))   # B^T U_b
     else:
@@ -215,7 +241,7 @@ def ingest(
             a_norm, k_batch, state.s, state.v, **statics)
         k_old = state.rank
         u_new = jnp.concatenate(
-            [state.u @ uk[:k_old], u_b @ uk[k_old:]], axis=0)
+            [mm(state.u, uk[:k_old]), mm(u_b, uk[k_old:])], axis=0)
     if obs.enabled():
         obs.counter_add("ingest_batches_total")
         obs.counter_add("ingest_rows_total", float(m_b))
@@ -270,7 +296,7 @@ def _merge_truncate_local(p_d: jnp.ndarray, axes: Tuple[str, ...],
     columns).  Returns (s_new (k_new,), w (k_tot, k_new) — the ``uk``
     rotation of ``hierarchy.merge_svd`` — and v_new_d (W, k_new))."""
     k_tot = p_d.shape[1]
-    g = jax.lax.psum(p_d.T @ p_d, axes)               # (k_tot, k_tot)
+    g = jax.lax.psum(mm(p_d.T, p_d), axes)            # (k_tot, k_tot)
     evals, evecs = jnp.linalg.eigh(g)                 # ascending
     evals = jnp.flip(evals, -1)
     evecs = jnp.flip(evecs, -1)
@@ -279,8 +305,55 @@ def _merge_truncate_local(p_d: jnp.ndarray, axes: Tuple[str, ...],
     good = evals[:k_new] > floor
     inv = jnp.where(good, 1.0 / jnp.where(good, s_all[:k_new], 1.0), 0.0)
     w = evecs[:, :k_new]
-    v_new_d = p_d @ (w * inv[None, :])
+    v_new_d = mm(p_d, w * inv[None, :])
     return s_all[:k_new], w, v_new_d
+
+
+def _dense_repair_shard(a_d: jnp.ndarray, key_d: jax.Array, *,
+                        axes: Tuple[str, ...], method: str) -> jnp.ndarray:
+    """This device's repaired (m_b, W) column block — same key chain and
+    same (psum'd == global) adjacency as the single-host split_and_repair
+    prologue, so the repaired batch is bit-identical to what the
+    single-host engine factors."""
+    m_b = a_d.shape[0]
+    adj = None
+    if method in ("neighbor", "neighbor_random"):
+        b = (a_d != 0).astype(jnp.float32)
+        adj = jax.lax.psum(b @ b.T, axes)
+        adj = (adj > 0) & ~jnp.eye(m_b, dtype=bool)
+    return ranky.repair_block(a_d, method, key_d, adj)
+
+
+def _sparse_repair_shard(ids, rows, vals, key_d: jax.Array, *, m: int,
+                         width: int, axes: Tuple[str, ...], method: str):
+    """(repair_cols, repair_mask) of this device's ELL block, keyed and
+    adjacency-matched like the single-host prologue."""
+    adj = None
+    if method in ("neighbor", "neighbor_random"):
+        p = sparse.stored_col_panel(rows, vals, m, binarize=True)
+        adj = jax.lax.psum(p.T @ p, axes)
+        adj = (adj > 0) & ~jnp.eye(m, dtype=bool)
+    return ranky.repair_block_sparse(ids, rows, vals, method, key_d,
+                                     m=m, width=width, row_adj=adj)
+
+
+def _dense_gram_shard(a_d, keys_d, *, axes, method, use_kernel):
+    """The exact path's first region: the psum'd (m_b, m_b) gram of the
+    repaired batch.  Its eigh runs outside (:func:`batch_left_vectors`)
+    and the merge region repairs again to form the panel."""
+    blk = _dense_repair_shard(a_d, keys_d[0], axes=axes, method=method)
+    return jax.lax.psum(lsvd.gram(blk, use_kernel=use_kernel), axes)
+
+
+def _sparse_gram_shard(ids, rows, vals, keys_d, *, m, width, axes, method,
+                       use_kernel):
+    """Sparse twin of :func:`_dense_gram_shard`."""
+    ids, rows, vals = ids[0], rows[0], vals[0]
+    rc, rm = _sparse_repair_shard(ids, rows, vals, keys_d[0], m=m,
+                                  width=width, axes=axes, method=method)
+    return jax.lax.psum(
+        lsvd.sparse_gram_block(ids, rows, vals, rc, rm, m,
+                               use_kernel=use_kernel), axes)
 
 
 def _dense_stream_shard_fn(
@@ -289,35 +362,23 @@ def _dense_stream_shard_fn(
     k_batch: jax.Array,     # replicated batch key (sketch Omega)
     v_d: jnp.ndarray,       # (W, k_old) this device's shard of state.v
     s_old: jnp.ndarray,     # (k_old,) decayed singular values, replicated
+    u_b: Optional[jnp.ndarray] = None,  # (m_b, r_b) exact path, replicated
     *,
     axes: Tuple[str, ...],
     method: str,
-    use_kernel: bool,
     r_b: int,
     k_new: int,
     sk_rank: Optional[int],
     oversample: int,
     power_iters: int,
 ):
-    key_d = keys_d[0]
     m_b = a_d.shape[0]
-    # Repair — same key chain and same (psum'd == global) adjacency as
-    # the single-host split_and_repair prologue, so the repaired batch
-    # is bit-identical to what the single-host engine factors.
-    adj = None
-    if method in ("neighbor", "neighbor_random"):
-        b = (a_d != 0).astype(jnp.float32)
-        adj = jax.lax.psum(b @ b.T, axes)
-        adj = (adj > 0) & ~jnp.eye(m_b, dtype=bool)
-    blk = ranky.repair_block(a_d, method, key_d, adj)
+    blk = _dense_repair_shard(a_d, keys_d[0], axes=axes, method=method)
     repaired = jax.lax.psum(
         ranky.lonely_rows(a_d).sum() - ranky.lonely_rows(blk).sum(), axes)
 
     if sk_rank is None:
-        g = jax.lax.psum(lsvd.gram(blk, use_kernel=use_kernel), axes)
-        u_b, _ = lsvd.eigh_to_svd(g)
-        u_b = u_b[:, :r_b]
-        panel_d = blk.T @ u_b                          # B_d^T U_b, (W, r_b)
+        panel_d = mm(blk.T, u_b)                       # B_d^T U_b, (W, r_b)
     else:
         u_b, s_b, v_b_d = randomized.randomized_tail_over(
             lambda om: randomized.sketch_block_dense(om, blk),
@@ -339,12 +400,12 @@ def _sparse_stream_shard_fn(
     k_batch: jax.Array,
     v_d: jnp.ndarray,
     s_old: jnp.ndarray,
+    u_b: Optional[jnp.ndarray] = None,
     *,
     m: int,
     width: int,
     axes: Tuple[str, ...],
     method: str,
-    use_kernel: bool,
     r_b: int,
     k_new: int,
     sk_rank: Optional[int],
@@ -352,22 +413,11 @@ def _sparse_stream_shard_fn(
     power_iters: int,
 ):
     ids, rows, vals = ids[0], rows[0], vals[0]
-    key_d = keys_d[0]
-    adj = None
-    if method in ("neighbor", "neighbor_random"):
-        p = sparse.stored_col_panel(rows, vals, m, binarize=True)
-        adj = jax.lax.psum(p.T @ p, axes)
-        adj = (adj > 0) & ~jnp.eye(m, dtype=bool)
-    rc, rm = ranky.repair_block_sparse(ids, rows, vals, method, key_d,
-                                       m=m, width=width, row_adj=adj)
+    rc, rm = _sparse_repair_shard(ids, rows, vals, keys_d[0], m=m,
+                                  width=width, axes=axes, method=method)
     repaired = jax.lax.psum(rm.sum(), axes)
 
     if sk_rank is None:
-        g = jax.lax.psum(
-            lsvd.sparse_gram_block(ids, rows, vals, rc, rm, m,
-                                   use_kernel=use_kernel), axes)
-        u_b, _ = lsvd.eigh_to_svd(g)
-        u_b = u_b[:, :r_b]
         panel_d = lsvd.sparse_right_vectors(
             ids, rows, vals, rc, rm, width, u_b,
             jnp.ones((r_b,), jnp.float32))             # B_d^T U_b
@@ -386,13 +436,22 @@ def _sparse_stream_shard_fn(
     return u_b, s_new, w, v_new_d, repaired
 
 
+def _delta_specs(kind: str, axes: Tuple[str, ...]) -> Tuple:
+    """in_specs of a delta: the ELL arrays (ids, rows, vals) split on
+    their leading block axis, or the dense delta's columns."""
+    if kind == "ell":
+        return (P(axes), P(axes), P(axes))
+    return (P(None, axes),)
+
+
 @functools.lru_cache(maxsize=64)
 def _sharded_ingest_fn(devices_key: Tuple[int, ...], d: int, kind: str,
                        m_b: int, width: int,
                        r_b: int, k_new: int, sk_rank: Optional[int],
-                       oversample: int, power_iters: int, method: str,
-                       use_kernel: bool):
-    """(mesh, jitted shard_map callable) for one static ingest shape.
+                       oversample: int, power_iters: int, method: str):
+    """(mesh, jitted shard_map callable) for one static ingest shape:
+    repair, the batch panel (from a given U_b on the exact path, from
+    the sketch otherwise) and the merge.
 
     Cached so a steady-state stream (same batch shape, state at
     truncate_rank) compiles its sharded update ONCE and replays it
@@ -405,23 +464,41 @@ def _sharded_ingest_fn(devices_key: Tuple[int, ...], d: int, kind: str,
     mesh is never reused."""
     mesh = stream_state.stream_mesh(d)
     axes = (STREAM_AXIS,)
-    common = dict(axes=axes, method=method, use_kernel=use_kernel,
-                  r_b=r_b, k_new=k_new, sk_rank=sk_rank,
-                  oversample=oversample, power_iters=power_iters)
+    common = dict(axes=axes, method=method, r_b=r_b, k_new=k_new,
+                  sk_rank=sk_rank, oversample=oversample,
+                  power_iters=power_iters)
     if kind == "ell":
         fn = functools.partial(_sparse_stream_shard_fn, m=m_b, width=width,
                                **common)
-        in_specs = (P(axes), P(axes), P(axes),      # ids, rows, vals
-                    P(axes), P(),                   # keys, k_batch
-                    P(axes, None), P())             # v, s_old
     else:
         fn = functools.partial(_dense_stream_shard_fn, **common)
-        in_specs = (P(None, axes),                  # delta columns
-                    P(axes), P(),                   # keys, k_batch
-                    P(axes, None), P())             # v, s_old
+    in_specs = _delta_specs(kind, axes) + (
+        P(axes), P(),                   # keys, k_batch
+        P(axes, None), P())             # v, s_old
+    if sk_rank is None:
+        in_specs += (P(),)              # U_b
     out_specs = (P(), P(), P(), P(axes, None), P())
     sharded = shard_map(fn, mesh=mesh, in_specs=in_specs,
                         out_specs=out_specs)
+    return mesh, jax.jit(sharded)
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_gram_fn(devices_key: Tuple[int, ...], d: int, kind: str,
+                     m_b: int, width: int, method: str, use_kernel: bool):
+    """(mesh, jitted shard_map callable): the exact path's batch gram,
+    psum'd and replicated — ``(delta..., keys) -> g (m_b, m_b)``."""
+    mesh = stream_state.stream_mesh(d)
+    axes = (STREAM_AXIS,)
+    common = dict(axes=axes, method=method, use_kernel=use_kernel)
+    if kind == "ell":
+        fn = functools.partial(_sparse_gram_shard, m=m_b, width=width,
+                               **common)
+    else:
+        fn = functools.partial(_dense_gram_shard, **common)
+    sharded = shard_map(fn, mesh=mesh,
+                        in_specs=_delta_specs(kind, axes) + (P(axes),),
+                        out_specs=P())
     return mesh, jax.jit(sharded)
 
 
@@ -457,12 +534,12 @@ def ingest_shard_map(
     s_old = state.s * jnp.float32(config.history_decay)
 
     sparse_in = isinstance(a_norm, sparse.BlockEll)
+    kind = "ell" if sparse_in else "dense"
+    width = a_norm.width if sparse_in else a_norm.shape[1] // d
     mesh, fn = _sharded_ingest_fn(
-        stream_state.stream_devices_key(),
-        d, "ell" if sparse_in else "dense", m_b,
-        a_norm.width if sparse_in else a_norm.shape[1] // d,
+        stream_state.stream_devices_key(), d, kind, m_b, width,
         r_b, k_new, plan.rank, config.oversample, config.power_iters,
-        config.method, config.use_kernel)
+        config.method)
     blk_sh = NamedSharding(mesh, P(STREAM_AXIS))
     rep_sh = NamedSharding(mesh, P())
     tail = (jax.device_put(keys, blk_sh),
@@ -476,6 +553,11 @@ def ingest_shard_map(
     else:
         args = (jax.device_put(a_norm,
                                NamedSharding(mesh, P(None, STREAM_AXIS))),)
+    if plan.rank is None:
+        _, gram_fn = _sharded_gram_fn(
+            stream_state.stream_devices_key(), d, kind, m_b, width,
+            config.method, config.use_kernel)
+        tail += (batch_left_vectors(gram_fn(*args, tail[0]), r_b, mesh),)
     if obs.enabled():
         # R5d drift: memory_analysis on the SPMD jit reports PER-DEVICE
         # sizes, matching streaming_bytes_per_device in the plan.
@@ -493,7 +575,7 @@ def ingest_shard_map(
         # ingestion order and only the small (k_tot, k_new) rotation
         # ever touches it.
         u_new = jnp.concatenate(
-            [state.u @ uk[:k_old], u_b @ uk[k_old:]], axis=0)
+            [mm(state.u, uk[:k_old]), mm(u_b, uk[k_old:])], axis=0)
     obs.counter_add("ingest_batches_total")
     obs.counter_add("ingest_rows_total", float(m_b))
 

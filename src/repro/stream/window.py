@@ -25,6 +25,13 @@ as one rolled ``lax.scan``:
   ``true_m``) before they ever reach ``u``.  Padding slots in the ELL
   arrays are all-zero values — inert by the container's own convention.
 
+* **Exact batch factorization outside the scan** — on the exact path
+  each batch's gram is its own small program and its eigh the one
+  program per batch height that every engine shares
+  (``ingest.batch_left_vectors``); the scan receives the batches' U_b in
+  ``xs``.  An eigh fused into the scan would recompile with every bucket
+  and window length, and on a TPU it is by far the costliest compile.
+
 * **Scan body** — the existing ingest math (repair -> factor -> panel
   merge) with the wrinkle that ``u`` grows with ``rows_seen`` and
   cannot live in a fixed-shape carry.  The carry holds
@@ -77,9 +84,11 @@ from repro.obs import clock
 from repro.compat import shard_map_nocheck as shard_map
 from repro.core import hierarchy, planner, randomized, ranky, sparse
 from repro.core import svd as lsvd
+from repro.precision import mm
 from repro.stream import state as stream_state
-from repro.stream.ingest import (IngestInfo, _fire_seam,
-                                 _merge_truncate_local)
+from repro.stream.ingest import (IngestInfo, _dense_repair_shard,
+                                 _fire_seam, _merge_truncate_local,
+                                 _sparse_repair_shard, batch_left_vectors)
 from repro.stream.state import STREAM_AXIS, StreamingSVDState
 
 # Smallest row bucket: padding everything below 8 rows to one shape
@@ -122,6 +131,8 @@ def clear_caches() -> None:
     """Forget every built scan (fresh compile-count measurements)."""
     _window_fn.cache_clear()
     _sharded_window_fn.cache_clear()
+    _gram_fn.cache_clear()
+    _sharded_gram_fn.cache_clear()
     _BUILT.clear()
     reset_dispatch_counts()
 
@@ -239,13 +250,11 @@ def adaptive_oversample(s, rank: int, base: int) -> int:
 # The scan step (single-host) — the ingest math with masked padding
 # ---------------------------------------------------------------------------
 
-def _step_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
-                 r_b: int, k_state: int, sk_rank: Optional[int],
-                 oversample: int, power_iters: int, method: str,
-                 use_kernel: bool, decay: float, key, carry, xs):
-    s, v, bidx, lonely_acc, repaired_acc = carry
+def _repair_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
+                   method: str, k_batch, xs):
+    """Repair one bucketed batch with its padded rows inert: (blocks,
+    lonely rows per block (D,), repairs made)."""
     tm = xs[-1]
-    k_batch = jax.random.fold_in(key, bidx)
     valid = jnp.arange(m_pad, dtype=jnp.int32) < tm      # (m_pad,) rows
 
     if kind == "dense":
@@ -273,11 +282,30 @@ def _step_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
         repaired_b = rm.sum().astype(jnp.int32)
 
     lonely_pb = lonely_mask.sum(axis=1).astype(jnp.int32)  # (D,)
+    return blocks, lonely_pb, repaired_b
+
+
+def _gram_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
+                 method: str, use_kernel: bool, key, bidx, *xs):
+    """The exact path's batch gram (m_pad, m_pad) of one bucketed batch:
+    the step's own repair, then the summed gram stack.  Its eigh runs
+    outside (``ingest.batch_left_vectors``)."""
+    blocks, _, _ = _repair_single(kind, d, m_pad, width, n_univ, method,
+                                  jax.random.fold_in(key, bidx), xs)
+    return lsvd.gram_stack(blocks, use_kernel=use_kernel).sum(axis=0)
+
+
+def _step_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
+                 r_b: int, k_state: int, sk_rank: Optional[int],
+                 oversample: int, power_iters: int, method: str,
+                 decay: float, key, carry, xs):
+    s, v, bidx, lonely_acc, repaired_acc = carry
+    k_batch = jax.random.fold_in(key, bidx)
+    blocks, lonely_pb, repaired_b = _repair_single(
+        kind, d, m_pad, width, n_univ, method, k_batch, xs)
 
     if sk_rank is None:
-        u_b, _ = lsvd.merge_grams_eigh(
-            lsvd.gram_stack(blocks, use_kernel=use_kernel))
-        u_b = u_b[:, :r_b]
+        u_b = xs[-2]                       # (m_pad, r_b), from the gram's eigh
         panel_b = ranky.right_vectors_stack(
             blocks, u_b, jnp.ones((r_b,), jnp.float32))
     else:
@@ -299,14 +327,16 @@ def _step_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
 def _window_fn(kind: str, d: int, m_pad: int, width: int, n_univ: int,
                r_b: int, k_state: int, sk_rank: Optional[int],
                oversample: int, power_iters: int, method: str,
-               use_kernel: bool, decay: float):
+               decay: float):
     """Jitted ``lax.scan`` ingest for one static bucket shape.  The jit
     cache keys on argument avals underneath, so every window length T
     of one bucket adds one trace to THIS callable (counted by
-    :func:`trace_count`); a new bucket shape builds a new callable."""
+    :func:`trace_count`); a new bucket shape builds a new callable.
+    On the exact path ``xs`` carries each batch's U_b (T, m_pad, r_b)
+    just before the row counts."""
     step = functools.partial(_step_single, kind, d, m_pad, width, n_univ,
                              r_b, k_state, sk_rank, oversample,
-                             power_iters, method, use_kernel, decay)
+                             power_iters, method, decay)
 
     @jax.jit
     def run(key, s, v, bidx, lonely0, repaired0, xs):
@@ -314,22 +344,30 @@ def _window_fn(kind: str, d: int, m_pad: int, width: int, n_univ: int,
                             (s, v, bidx, lonely0, repaired0), xs)
 
     _BUILT[("single", kind, d, m_pad, width, n_univ, r_b, k_state, sk_rank,
-            oversample, power_iters, method, use_kernel, decay)] = run
+            oversample, power_iters, method, decay)] = run
     return run
+
+
+@functools.lru_cache(maxsize=64)
+def _gram_fn(kind: str, d: int, m_pad: int, width: int, n_univ: int,
+             method: str, use_kernel: bool):
+    """Jitted :func:`_gram_single` for one bucket shape:
+    ``(key, bidx, *batch) -> g``."""
+    return jax.jit(functools.partial(_gram_single, kind, d, m_pad, width,
+                                     n_univ, method, use_kernel))
 
 
 # ---------------------------------------------------------------------------
 # The scan step (shard_map) — scan INSIDE the region, v sharded in carry
 # ---------------------------------------------------------------------------
 
-def _step_sharded(kind: str, d: int, m_pad: int, width: int,
-                  r_b: int, k_state: int, sk_rank: Optional[int],
-                  oversample: int, power_iters: int, method: str,
-                  use_kernel: bool, decay: float,
-                  axes: Tuple[str, ...], key, carry, xs):
-    s, v_d, bidx, lonely_acc, repaired_acc = carry
+def _repair_sharded(kind: str, d: int, m_pad: int, width: int,
+                    method: str, axes: Tuple[str, ...], k_batch, xs):
+    """Repair this device's block of one bucketed batch, padded rows
+    inert: (repaired block — dense (m_pad, W) or the ELL arrays with
+    their repair side-band — this device's lonely rows, repairs made
+    across the mesh)."""
     tm = xs[-1]
-    k_batch = jax.random.fold_in(key, bidx)
     # Device d draws split(k_batch, D)[d] — the exact key the
     # single-host split_and_repair hands block d.
     key_d = jax.random.split(k_batch, d)[jax.lax.axis_index(axes[0])]
@@ -338,61 +376,64 @@ def _step_sharded(kind: str, d: int, m_pad: int, width: int,
     if kind == "dense":
         a_d = xs[0]                                      # (m_pad, W)
         lon_d = (ranky.lonely_rows(a_d) & valid).sum().astype(jnp.int32)
-        adj = None
-        if method in ("neighbor", "neighbor_random"):
-            b = (a_d != 0).astype(jnp.float32)
-            adj = jax.lax.psum(b @ b.T, axes)
-            adj = (adj > 0) & ~jnp.eye(m_pad, dtype=bool)
-        blk = ranky.repair_block(a_d, method, key_d, adj)
+        blk = _dense_repair_shard(a_d, key_d, axes=axes, method=method)
         blk = jnp.where(valid[:, None], blk, 0.0)        # padded rows inert
         still = (ranky.lonely_rows(blk) & valid).sum().astype(jnp.int32)
-        repaired_b = jax.lax.psum(lon_d - still, axes)
+        return blk, lon_d, jax.lax.psum(lon_d - still, axes)
 
-        if sk_rank is None:
-            g = jax.lax.psum(lsvd.gram(blk, use_kernel=use_kernel), axes)
-            u_b, _ = lsvd.eigh_to_svd(g)
-            u_b = u_b[:, :r_b]
-            panel_d = blk.T @ u_b
-        else:
-            u_b, s_b, v_b_d = randomized.randomized_tail_over(
-                lambda om: randomized.sketch_block_dense(om, blk),
-                lambda gg: randomized.pullback_block_dense(gg, blk),
-                axes, m_pad, rank=sk_rank, oversample=oversample,
-                power_iters=power_iters, key=k_batch, want_right=True)
-            panel_d = v_b_d * s_b[None, :]
+    ids, rows, vals = xs[0][0], xs[1][0], xs[2][0]       # (C,), (C, K) x2
+    lon_row = ranky.sparse_lonely_rows(rows, vals, m_pad) & valid
+    lon_d = lon_row.sum().astype(jnp.int32)
+    rc, rm = _sparse_repair_shard(ids, rows, vals, key_d, m=m_pad,
+                                  width=width, axes=axes, method=method)
+    rm = rm & valid                                      # padded rows inert
+    return ((ids, rows, vals, rc, rm), lon_d,
+            jax.lax.psum(rm.sum().astype(jnp.int32), axes))
+
+
+def _gram_sharded(kind: str, d: int, m_pad: int, width: int, method: str,
+                  use_kernel: bool, axes: Tuple[str, ...], key, bidx, *xs):
+    """Sharded twin of :func:`_gram_single`: the psum'd batch gram,
+    replicated."""
+    blk, _, _ = _repair_sharded(kind, d, m_pad, width, method, axes,
+                                jax.random.fold_in(key, bidx), xs)
+    if kind == "dense":
+        g = lsvd.gram(blk, use_kernel=use_kernel)
     else:
-        ids, rows, vals = xs[0][0], xs[1][0], xs[2][0]   # (C,), (C, K) x2
-        lon_row = ranky.sparse_lonely_rows(rows, vals, m_pad) & valid
-        lon_d = lon_row.sum().astype(jnp.int32)
-        adj = None
-        if method in ("neighbor", "neighbor_random"):
-            pan = sparse.stored_col_panel(rows, vals, m_pad, binarize=True)
-            adj = jax.lax.psum(pan.T @ pan, axes)
-            adj = (adj > 0) & ~jnp.eye(m_pad, dtype=bool)
-        rc, rm = ranky.repair_block_sparse(ids, rows, vals, method, key_d,
-                                           m=m_pad, width=width,
-                                           row_adj=adj)
-        rm = rm & valid                                  # padded rows inert
-        repaired_b = jax.lax.psum(rm.sum().astype(jnp.int32), axes)
+        g = lsvd.sparse_gram_block(*blk, m_pad, use_kernel=use_kernel)
+    return jax.lax.psum(g, axes)
 
-        if sk_rank is None:
-            g = jax.lax.psum(
-                lsvd.sparse_gram_block(ids, rows, vals, rc, rm, m_pad,
-                                       use_kernel=use_kernel), axes)
-            u_b, _ = lsvd.eigh_to_svd(g)
-            u_b = u_b[:, :r_b]
-            panel_d = lsvd.sparse_right_vectors(
-                ids, rows, vals, rc, rm, width, u_b,
-                jnp.ones((r_b,), jnp.float32))
+
+def _step_sharded(kind: str, d: int, m_pad: int, width: int,
+                  r_b: int, k_state: int, sk_rank: Optional[int],
+                  oversample: int, power_iters: int, method: str,
+                  decay: float, axes: Tuple[str, ...], key, carry, xs):
+    s, v_d, bidx, lonely_acc, repaired_acc = carry
+    k_batch = jax.random.fold_in(key, bidx)
+    blk, lon_d, repaired_b = _repair_sharded(kind, d, m_pad, width, method,
+                                             axes, k_batch, xs)
+
+    if sk_rank is None:
+        u_b = xs[-2]                       # (m_pad, r_b), from the gram's eigh
+        if kind == "dense":
+            panel_d = mm(blk.T, u_b)
         else:
-            u_b, s_b, v_b_d = randomized.randomized_tail_over(
-                lambda om: randomized.sketch_block_sparse(
-                    om, ids, rows, vals, rc, rm, width),
-                lambda gg: randomized.pullback_block_sparse(
-                    gg, ids, rows, vals, rc, rm, m_pad),
-                axes, m_pad, rank=sk_rank, oversample=oversample,
-                power_iters=power_iters, key=k_batch, want_right=True)
-            panel_d = v_b_d * s_b[None, :]
+            panel_d = lsvd.sparse_right_vectors(
+                *blk, width, u_b, jnp.ones((r_b,), jnp.float32))
+    elif kind == "dense":
+        u_b, s_b, v_b_d = randomized.randomized_tail_over(
+            lambda om: randomized.sketch_block_dense(om, blk),
+            lambda gg: randomized.pullback_block_dense(gg, blk),
+            axes, m_pad, rank=sk_rank, oversample=oversample,
+            power_iters=power_iters, key=k_batch, want_right=True)
+        panel_d = v_b_d * s_b[None, :]
+    else:
+        u_b, s_b, v_b_d = randomized.randomized_tail_over(
+            lambda om: randomized.sketch_block_sparse(om, *blk, width),
+            lambda gg: randomized.pullback_block_sparse(gg, *blk, m_pad),
+            axes, m_pad, rank=sk_rank, oversample=oversample,
+            power_iters=power_iters, key=k_batch, want_right=True)
+        panel_d = v_b_d * s_b[None, :]
 
     s_old = s * jnp.float32(decay)
     p_d = jnp.concatenate([v_d * s_old[None, :], panel_d], axis=1)
@@ -405,39 +446,76 @@ def _step_sharded(kind: str, d: int, m_pad: int, width: int,
     return carry, (uk, u_b, lon_d[None])
 
 
+def _batch_specs(kind: str, axes: Tuple[str, ...], stacked: bool) -> Tuple:
+    """in_specs of a bucketed batch's arrays before its row count: the
+    ELL arrays split on their block axis, a dense batch on its columns
+    (after a leading window axis when ``stacked``)."""
+    lead = (None,) if stacked else ()
+    if kind == "ell":
+        return (P(*lead, axes),) * 3
+    return (P(*lead, None, axes),)
+
+
 @functools.lru_cache(maxsize=64)
 def _sharded_window_fn(devices_key: Tuple[int, ...], kind: str, d: int,
                        m_pad: int, width: int,
                        r_b: int, k_state: int, sk_rank: Optional[int],
                        oversample: int, power_iters: int, method: str,
-                       use_kernel: bool, decay: float):
+                       decay: float):
     """(mesh, jitted shard_map scan) for one static bucket shape.  The
     scan lives INSIDE the region: ``v`` stays column-block-sharded in
     the carry across the whole window and the per-step collectives are
     exactly ``ingest_shard_map``'s, so rule R5d's per-device flat peak
-    holds for the window (rule R6's per-device form)."""
+    holds for the window (rule R6's per-device form).  On the exact path
+    ``xs`` carries each batch's replicated U_b before the row counts."""
     mesh = stream_state.stream_mesh(d)
     axes = (STREAM_AXIS,)
     step = functools.partial(_step_sharded, kind, d, m_pad, width,
                              r_b, k_state, sk_rank, oversample,
-                             power_iters, method, use_kernel, decay, axes)
+                             power_iters, method, decay, axes)
 
     def region(key, s, v_d, bidx, lonely0, repaired0, *xs):
         return jax.lax.scan(functools.partial(step, key),
                             (s, v_d, bidx, lonely0, repaired0), xs)
 
-    if kind == "ell":
-        xs_specs = (P(None, axes), P(None, axes), P(None, axes), P())
-    else:
-        xs_specs = (P(None, None, axes), P())
-    in_specs = (P(), P(), P(axes, None), P(), P(), P()) + xs_specs
+    xs_specs = _batch_specs(kind, axes, stacked=True)
+    if sk_rank is None:
+        xs_specs += (P(),)                               # U_b
+    in_specs = (P(), P(), P(axes, None), P(), P(), P()) + xs_specs + (P(),)
     out_specs = ((P(), P(axes, None), P(), P(), P()),   # carry
                  (P(), P(), P(None, axes)))             # uk, u_b, lonely
     fn = jax.jit(shard_map(region, mesh=mesh,
                            in_specs=in_specs, out_specs=out_specs))
     _BUILT[("shard_map", kind, d, m_pad, width, r_b, k_state, sk_rank,
-            oversample, power_iters, method, use_kernel, decay)] = fn
+            oversample, power_iters, method, decay)] = fn
     return mesh, fn
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_gram_fn(devices_key: Tuple[int, ...], kind: str, d: int,
+                     m_pad: int, width: int, method: str, use_kernel: bool):
+    """Jitted shard_map :func:`_gram_sharded` for one bucket shape:
+    ``(key, bidx, *batch) -> g`` replicated."""
+    mesh = stream_state.stream_mesh(d)
+    axes = (STREAM_AXIS,)
+    fn = functools.partial(_gram_sharded, kind, d, m_pad, width, method,
+                           use_kernel, axes)
+    in_specs = (P(), P()) + _batch_specs(kind, axes, stacked=False) + (P(),)
+    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=P()))
+
+
+def _window_left_vectors(gram_fn, key, bidx0: int, xs, r_b: int,
+                         mesh=None) -> jnp.ndarray:
+    """Each batch's U_b, stacked (T, m_pad, r_b): the batch gram from
+    ``gram_fn``, then the eigh program shared by every engine
+    (``ingest.batch_left_vectors``, keyed on m_pad alone)."""
+    t_len = int(xs[-1].shape[0])
+    return jnp.stack([
+        batch_left_vectors(
+            gram_fn(key, jnp.asarray(bidx0 + t, jnp.int32),
+                    *(x[t] for x in xs)), r_b, mesh)
+        for t in range(t_len)])
 
 
 # ---------------------------------------------------------------------------
@@ -451,7 +529,8 @@ def ingest_window(
     plan,
 ) -> Tuple[StreamingSVDState, IngestInfo]:
     """Fold a window of same-bucket batches into the state with ONE
-    jitted dispatch (see module docstring).
+    jitted scan dispatch (see module docstring; the exact path first
+    dispatches each batch's gram and eigh, with no host sync).
 
     ``deltas`` must share one :func:`bucket_signature`; the state must
     already sit at ``config.truncate_rank`` (the scan carry is
@@ -495,27 +574,32 @@ def ingest_window(
 
     bidx0 = jnp.asarray(state.batches_seen, jnp.int32)
     zero = jnp.asarray(0, jnp.int32)
+    exact = plan.rank is None
     common = (kind, d, m_pad, width, r_b, k, plan.rank,
               config.oversample, config.power_iters, config.method,
-              config.use_kernel, float(config.history_decay))
+              float(config.history_decay))
 
     if plan.backend == "shard_map":
-        mesh, fn = _sharded_window_fn(
-            stream_state.stream_devices_key(), *common)
+        devices_key = stream_state.stream_devices_key()
+        mesh, fn = _sharded_window_fn(devices_key, *common)
         rep_sh = NamedSharding(mesh, P())
         v0 = jax.device_put(state.v, NamedSharding(mesh,
                                                    P(STREAM_AXIS, None)))
-        if kind == "ell":
-            blk3 = NamedSharding(mesh, P(None, STREAM_AXIS))
-            xs_dev = tuple(jax.device_put(x, blk3) for x in xs[:3]) + (
-                jax.device_put(xs[3], rep_sh),)
-        else:
-            xs_dev = (jax.device_put(xs[0],
-                                     NamedSharding(mesh,
-                                                   P(None, None,
-                                                     STREAM_AXIS))),
-                      jax.device_put(xs[1], rep_sh))
-        call_args = (jax.device_put(state.key, rep_sh),
+        batch_sh = [NamedSharding(mesh, spec) for spec in
+                    _batch_specs(kind, (STREAM_AXIS,), stacked=True)]
+        xs_dev = tuple(jax.device_put(x, sh)
+                       for x, sh in zip(xs, batch_sh)) + (
+            jax.device_put(xs[-1], rep_sh),)
+        key_dev = jax.device_put(state.key, rep_sh)
+        if exact:
+            gram_fn = _sharded_gram_fn(devices_key, kind, d, m_pad, width,
+                                       config.method, config.use_kernel)
+            u_bs = _window_left_vectors(gram_fn, key_dev,
+                                        state.batches_seen, xs_dev, r_b,
+                                        mesh)
+            xs_dev = xs_dev[:-1] + (jax.device_put(u_bs, rep_sh),
+                                    xs_dev[-1])
+        call_args = (key_dev,
                      jax.device_put(state.s, rep_sh), v0,
                      jax.device_put(bidx0, rep_sh),
                      jax.device_put(zero, rep_sh),
@@ -525,8 +609,13 @@ def ingest_window(
         # ride along as statics of the single-host builder.
         fn = _window_fn(kind, d, m_pad, width, n_univ, r_b, k, plan.rank,
                         config.oversample, config.power_iters,
-                        config.method, config.use_kernel,
-                        float(config.history_decay))
+                        config.method, float(config.history_decay))
+        if exact:
+            gram_fn = _gram_fn(kind, d, m_pad, width, n_univ,
+                               config.method, config.use_kernel)
+            u_bs = _window_left_vectors(gram_fn, state.key,
+                                        state.batches_seen, xs, r_b)
+            xs = xs[:-1] + (u_bs, xs[-1])
         call_args = (state.key, state.s, state.v, bidx0, zero, zero, xs)
 
     # Merge-phase fault seam: brackets the one compiled dispatch (a
@@ -582,7 +671,7 @@ def ingest_window(
     for t in range(t_len):
         uk_t = uk_stack[t]
         ub_t = ub_stack[t, :true_m[t]]
-        u = jnp.concatenate([u @ uk_t[:k], ub_t @ uk_t[k:]], axis=0)
+        u = jnp.concatenate([mm(u, uk_t[:k]), mm(ub_t, uk_t[k:])], axis=0)
 
     # The ONE host materialization of the window: the side-band counters
     # lived on device the whole way (no per-batch sync).

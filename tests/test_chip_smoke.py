@@ -14,11 +14,11 @@ from repro.core import api, planner  # noqa: E402
 
 D = 8
 STREAM = dict(items=4096, draws_per_row=48, batch_rows=128, batches=8,
-              groups=8, popularity=40.0, seed=25)
+              groups=8, seed=25)
 RANK = 8
 # What this small stream reaches on the CPU (float32 XLA CPU, measured
 # once at this size and seed) — its truncation error by design.
-STREAM_CPU = {"sv_rel": 0.005266535610502002, "v_sin": 0.028674197481282915}
+STREAM_CPU = {"sv_rel": 0.009277063356082632, "v_sin": 0.049054128811818434}
 
 
 @pytest.fixture(scope="module")
@@ -128,3 +128,31 @@ def test_four_chip_phases_on_four_host_devices():
     for line in ("oneshot shard_map vs single", "stream shard_map vs single",
                  "serve shard_map vs single", '"v_devices": 4'):
         assert line in out, out
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_lands_in_one_place(tmp_path, from_env):
+    # JAX_COMPILATION_CACHE_DIR wins when set; otherwise .jax_cache/ in
+    # the checkout.  A fresh process, so no other test's JAX config leaks.
+    import subprocess
+
+    from repro import compile_cache
+
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro import compile_cache\n"
+        "path = compile_cache.enable()\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)).block_until_ready()\n"
+        "print(path, compile_cache.usage(path)['entries'])\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env, text=True,
+                         capture_output=True, timeout=300, cwd=tmp_path)
+    assert out.returncode == 0, out.stderr[-2000:]
+    path, entries = out.stdout.split()
+    want = tmp_path if from_env else compile_cache.CHECKOUT_CACHE_DIR
+    assert path == str(want) and int(entries) >= 1

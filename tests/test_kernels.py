@@ -100,19 +100,24 @@ def test_sparse_gram_tiled_output(m, block_m):
 def test_sparse_gram_ops_tiles_unaligned_m(monkeypatch, m):
     # M past one tile: ops pads M to the 128-aligned tile grid and trims.
     monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    monkeypatch.setattr(ops, "MAX_GRAM_BLOCK", 128)
+    monkeypatch.setattr(ops, "MAX_GRAM_TILE", 128)
     rows, vals = _random_ell(m, 200, 5, seed=4)
-    got = ops.sparse_gram(rows, vals, m, block_c=128, block_m=128)
+    got = ops.sparse_gram(rows, vals, m, block_c=128)
     assert got.shape == (m, m)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(ref.sparse_gram(rows, vals, m)),
                                rtol=2e-5, atol=1e-4)
 
 
-@pytest.mark.parametrize("m,block_m,want", [
-    (13, 512, (16, 16)), (544, 512, (384, 768)), (2048, 512, (512, 2048)),
-    (2049, 512, (512, 2560))])
-def test_gram_tiles(m, block_m, want):
-    assert ops._gram_tiles(m, block_m) == want
+@pytest.mark.parametrize("m,want", [
+    (13, (16, 16)), (544, (544, 544)), (640, (640, 640)),
+    (700, (384, 768)), (2048, (512, 2048)), (2049, (512, 2560))])
+def test_gram_tiles(m, want):
+    # One block up to MAX_GRAM_BLOCK rows; past it the fewest tiles of at
+    # most MAX_GRAM_TILE rows, each 128-aligned, so M pads by less than
+    # 128 rows per tile.
+    assert ops._gram_tiles(m) == want
 
 
 def test_kernel_width_limits_raise(monkeypatch):

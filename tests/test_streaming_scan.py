@@ -328,17 +328,19 @@ def test_r6_measured_peak_within_closed_form(memory_checker):
            else plan.rank)
     fn = sw._window_fn("dense", 8, SPEC.m, 512, 4096, r_b, 16,
                        plan.rank, cfg.oversample, cfg.power_iters,
-                       cfg.method, cfg.use_kernel,
-                       float(cfg.history_decay))
+                       cfg.method, float(cfg.history_decay))
     key = jax.random.PRNGKey(0)
     f32 = jnp.float32
     T = 4
+    # the exact path's xs carry each batch's U_b before the row counts
+    u_b = ((jax.ShapeDtypeStruct((T, SPEC.m, r_b), f32),)
+           if plan.rank is None else ())
     args = (key, jax.ShapeDtypeStruct((16,), f32),
             jax.ShapeDtypeStruct((4096, 16), f32),
             jax.ShapeDtypeStruct((), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32),
             jax.ShapeDtypeStruct((), jnp.int32),
-            (jax.ShapeDtypeStruct((T, SPEC.m, 4096), f32),
+            (jax.ShapeDtypeStruct((T, SPEC.m, 4096), f32), *u_b,
              jax.ShapeDtypeStruct((T,), jnp.int32)))
     budget = planner.window_bytes(SPEC, 16, cfg.oversample,
                                   exact=plan.rank is None, window=T,

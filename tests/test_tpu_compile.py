@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import api
 from repro.kernels import ops
 
 
@@ -55,8 +54,7 @@ def chip_compile(one_chip, monkeypatch):
     def compile_text(fn, *specs):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in specs]
-        with jax.default_matmul_precision(api.MATMUL_PRECISION):
-            return jax.jit(fn).lower(*args).compile().as_text()
+        return jax.jit(fn).lower(*args).compile().as_text()
 
     yield compile_text
     jax.config.update("jax_enable_compilation_cache", was)
