@@ -602,7 +602,7 @@ class _CallTimer:
         if config is not None and config.observe and not obs.enabled():
             obs.enable()
         clock.install_compile_probe()
-        self._e0 = len(obs.trace.events()) if obs.enabled() else 0
+        self._e0 = obs.trace.appended()
         self._t0 = clock.now()
         self._c0 = clock.compile_seconds()
 
@@ -615,7 +615,7 @@ class _CallTimer:
         if obs.enabled():
             out["drift_ratios"] = obs.drift_ratios()
             out["span_summary"] = obs.trace.span_summary(
-                obs.trace.events()[self._e0:])
+                obs.trace.since(self._e0))
         return out
 
 
@@ -646,15 +646,17 @@ def svd(a: MatrixInput, config: Optional[SolveConfig] = None, *,
             f"mesh only applies to backend='shard_map' (or 'auto')")
 
     timer = _CallTimer(config)
-    d, note = _resolve_num_blocks(a, config, mesh, block_axes)
-    spec = describe(a, d)
-    if config.rank is not None and config.rank > spec.m:
-        raise ValueError(f"rank={config.rank} must be in [1, M={spec.m}]")
-    device_count, mesh_provided = _device_env(mesh, block_axes)
-    p = planner.make_plan(spec, config, device_count=device_count,
-                          mesh_provided=mesh_provided)
-    if note:
-        p = dataclasses.replace(p, reasons=p.reasons + (note,))
+    with obs.span("svd.plan"):
+        d, note = _resolve_num_blocks(a, config, mesh, block_axes)
+        spec = describe(a, d)
+        if config.rank is not None and config.rank > spec.m:
+            raise ValueError(
+                f"rank={config.rank} must be in [1, M={spec.m}]")
+        device_count, mesh_provided = _device_env(mesh, block_axes)
+        p = planner.make_plan(spec, config, device_count=device_count,
+                              mesh_provided=mesh_provided)
+        if note:
+            p = dataclasses.replace(p, reasons=p.reasons + (note,))
 
     # local_mode is only consumed by the exact proxy merge; under the
     # gram merge (or the randomized path) a local_mode='svd' config
@@ -667,7 +669,10 @@ def svd(a: MatrixInput, config: Optional[SolveConfig] = None, *,
             "but the input is a sparse.BlockEll (the sparse path is "
             "gram-native); pass a dense array or COOMatrix, or use "
             "local_mode='gram'")
-    a_norm = as_block_input(a, d, needs_dense=needs_dense)
+    with obs.span("svd.convert", nnz=spec.nnz) as args:
+        a_norm = as_block_input(a, d, needs_dense=needs_dense)
+        if isinstance(a_norm, sparse.BlockEll):
+            args["ell_slots"] = int(a_norm.col_vals.size)
     # Materialize the plan's decisions into the config the engine runs
     # with: p.rank is None when the plan is "solve exactly, truncate
     # after" (truncate_to), so every backend sees the same decision.
@@ -696,25 +701,29 @@ def svd(a: MatrixInput, config: Optional[SolveConfig] = None, *,
             raise AssertionError(
                 f"planner produced unknown backend {p.backend!r}")
 
-    u, s = out[0], out[1]
-    v = out[2] if config.want_right else None
-    if p.truncate_to is not None:
-        k = p.truncate_to
-        u, s = u[:, :k], s[:k]
-        v = v[:, :k] if v is not None else None
-    jax.block_until_ready((u, s) if v is None else (u, s, v))
-    if v is not None:
-        v = v[:spec.n]  # trim the adapter's zero-column padding back off
+    with obs.span("svd.wait"):
+        u, s = out[0], out[1]
+        v = out[2] if config.want_right else None
+        if p.truncate_to is not None:
+            k = p.truncate_to
+            u, s = u[:, :k], s[:k]
+            v = v[:, :k] if v is not None else None
+        jax.block_until_ready((u, s) if v is None else (u, s, v))
+        if v is not None:
+            v = v[:spec.n]  # trim the adapter's zero-column padding back off
     timing = timer.finish()
 
-    lonely = ranky.lonely_rows_per_block(a_norm, d)
-    lonely_total = sum(lonely)
+    with obs.span("svd.diagnostics") as args:
+        lonely = ranky.lonely_rows_per_block(a_norm, d)
+        lonely_total = sum(lonely)
+        repaired = _repaired_rows(a_norm, d, config.method,
+                                  config.resolved_key(), lonely_total,
+                                  spec.m)
+        args.update(lonely_rows=lonely_total, repaired_rows=repaired)
     diag = Diagnostics(
         lonely_rows_per_block=lonely,
         lonely_rows=lonely_total,
-        repaired_rows=_repaired_rows(a_norm, d, config.method,
-                                     config.resolved_key(), lonely_total,
-                                     spec.m),
+        repaired_rows=repaired,
         strategy=p.strategy,
         estimated_peak_bytes=p.estimated_peak_bytes,
         **timing,

@@ -46,8 +46,10 @@ def merge_svd(p: jnp.ndarray, rank: int):
     m, rtot = p.shape
     # The span is inert inside jit/scan tracing (trace_state_clean guard
     # in obs.trace) — it records only for eager merges, e.g. the
-    # per-batch streaming ingest.
-    with obs.span("merge.svd", m=m, r_tot=rtot, rank=rank):
+    # per-batch streaming ingest.  The scope names the merge's device
+    # ops wherever it is traced (the scan window's step among them).
+    with obs.span("merge.svd", m=m, r_tot=rtot, rank=rank), \
+            jax.named_scope("stream.merge"):
         u, s, wt = jnp.linalg.svd(p, full_matrices=False)
         k = min(m, rtot)
         if k < rank:

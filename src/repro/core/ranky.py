@@ -453,7 +453,11 @@ def solve_single(
     if key is None:
         key = default_key()
 
-    blocks = split_and_repair(a, num_blocks, method, key)
+    # Stable scope names for the device ops (the trace's ``tf_op``):
+    # ranky.repair, ranky.gram, ranky.eigh (inside merge_grams_eigh),
+    # ranky.right.
+    with jax.named_scope("ranky.repair"):
+        blocks = split_and_repair(a, num_blocks, method, key)
 
     if rank is not None:
         from repro.core import randomized
@@ -463,8 +467,9 @@ def solve_single(
             power_iters=power_iters, key=key, want_right=want_right)
 
     if merge_mode == "gram":
-        u, s = lsvd.merge_grams_eigh(
-            lsvd.gram_stack(blocks, use_kernel=use_kernel))
+        with jax.named_scope("ranky.gram"):
+            grams = lsvd.gram_stack(blocks, use_kernel=use_kernel)
+        u, s = lsvd.merge_grams_eigh(grams)
     elif merge_mode == "proxy":
         if local_mode == "gram":
             us = lsvd.local_svd_gram_stack(blocks, use_kernel=use_kernel)
@@ -494,7 +499,8 @@ def solve_single(
 
     if not want_right:
         return u, s
-    return u, s, right_vectors_stack(blocks, u, s)
+    with jax.named_scope("ranky.right"):
+        return u, s, right_vectors_stack(blocks, u, s)
 
 
 def ranky_svd(

@@ -173,9 +173,11 @@ def merge_grams_eigh(grams: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     replaces the proxy SVD entirely.
 
     grams: (D, M, M) local gram matrices (or a pre-reduced (M, M)).
+    Its ops carry the scope ``ranky.eigh`` wherever it runs.
     """
-    g = grams.sum(axis=0) if grams.ndim == 3 else grams
-    return eigh_to_svd(g)
+    with jax.named_scope("ranky.eigh"):
+        g = grams.sum(axis=0) if grams.ndim == 3 else grams
+        return eigh_to_svd(g)
 
 
 def right_vectors(

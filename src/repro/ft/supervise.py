@@ -40,13 +40,13 @@ multi-host deployment) and scaled by the worst plan-vs-measured drift
 ratio, feeds ``StragglerMonitor.observe_window``.  A flagged slot with
 ``backup_ingest=True`` gets **backup-shard duplicate-ingest**: an idle
 healthy device outside the mesh shadows the slow slot's shard, and the
-chunk completes at the backup's (median) speed — accounted as
-``straggler_backup_total`` / ``backup_saved_seconds`` (on forced-host
-CPU simulation every slot shares one physical clock, so the saving is
-accounting, not wall time — the POLICY, which slots evict vs shadow,
-is the real thing under test).  A slot whose RAW time stays flagged for
-``patience`` consecutive windows under ``policy="evict"`` is evicted
-through the same recovery path as a kill.
+chunk completes at the backup's (median) speed — accounted in
+``backup_saved_s`` (on forced-host CPU simulation every slot shares one
+physical clock, so the saving is accounting, not wall time — the
+POLICY, which slots evict vs shadow, is the real thing under test).  A
+slot whose RAW time stays flagged for ``patience`` consecutive windows
+under ``policy="evict"`` is evicted through the same recovery path as a
+kill.
 """
 from __future__ import annotations
 
@@ -166,7 +166,6 @@ class StreamSupervisor:
             # inheriting the evicted straggler's flag streak would get
             # a healthy survivor evicted on the next window.
             self._monitor = StragglerMonitor(self.straggler_cfg, slots)
-        obs.gauge_set("stream_healthy_devices", float(len(self.healthy)))
 
     @property
     def backend(self) -> str:
@@ -205,7 +204,6 @@ class StreamSupervisor:
                     default=None)
         verdict = self._monitor.observe_window(dur_s, factors, drift=drift)
         for slot in verdict["flagged"]:
-            obs.counter_add("straggler_flagged_total")
             if self.backup_ingest and slot not in verdict["evict"]:
                 # Backup-shard duplicate-ingest: shadow the flagged
                 # slot's shard on an idle healthy device; the chunk
@@ -213,8 +211,6 @@ class StreamSupervisor:
                 # duplicate work, not wall time.
                 saved = dur_s * max(0.0, factors[slot] - 1.0)
                 self.backup_saved_s += saved
-                obs.counter_add("straggler_backup_total")
-                obs.counter_add("backup_saved_seconds", saved)
         return verdict
 
     # -- recovery ----------------------------------------------------------
@@ -276,10 +272,6 @@ class StreamSupervisor:
             retries=retries, wall_s=wall,
             r8_peak_bytes=rplan.peak_bytes, reasons=rplan.reasons)
         self.events.append(event)
-        obs.counter_add("recovery_events_total", labels={"kind": kind})
-        obs.event("recover.resume", kind=kind,
-                  survivors=len(self.healthy),
-                  resumed_from_batch=int(restored.batches_seen))
 
     # -- the supervised stream loop ---------------------------------------
 
@@ -310,7 +302,6 @@ class StreamSupervisor:
                                         state=self.state)
             except CollectiveDropError as e:
                 attempt += 1
-                obs.counter_add("ingest_retries_total")
                 if attempt > self.config.max_retries:
                     # Bounded retry exhausted: escalate to the full
                     # device-loss path (re-plan + restore) — the
@@ -334,8 +325,6 @@ class StreamSupervisor:
                         f"{attempt}/{self.config.max_retries}) — the "
                         f"PRNG chain keys on batches_seen, so the retry "
                         f"is bit-identical",)))
-                obs.counter_add("recovery_events_total",
-                                labels={"kind": "collective_retry"})
                 if self.config.retry_backoff_s:
                     time.sleep(self.config.retry_backoff_s
                                * (2 ** (attempt - 1)))
@@ -357,7 +346,6 @@ class StreamSupervisor:
                 # committed) boundary; remaining evictees get caught on
                 # later windows against the re-meshed monitor.
                 slot = verdict["evict"][0]
-                obs.counter_add("straggler_evictions_total")
                 self._recover("straggler_evict", hi - 1,
                               self.healthy[slot], self._m_hint(chunk))
                 i = int(self.state.batches_seen) - self._base

@@ -125,7 +125,7 @@ def topk_score(
     valid2 = jnp.asarray(valid, jnp.int32).reshape(1, 1)
     offset2 = jnp.asarray(offset, jnp.int32).reshape(1, 1)
     kernel = functools.partial(_topk_score_kernel, k_top=k_top)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -152,4 +152,6 @@ def topk_score(
             pltpu.VMEM((b, k_top), jnp.int32),
         ],
         interpret=interpret,
-    )(valid2, offset2, qs, v, scale)
+    )
+    with jax.named_scope("serve.topk"):
+        return call(valid2, offset2, qs, v, scale)

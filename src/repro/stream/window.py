@@ -80,7 +80,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.obs import clock
 from repro.compat import shard_map_nocheck as shard_map
 from repro.core import hierarchy, planner, randomized, ranky, sparse
 from repro.core import svd as lsvd
@@ -290,9 +289,12 @@ def _gram_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
     """The exact path's batch gram (m_pad, m_pad) of one bucketed batch:
     the step's own repair, then the summed gram stack.  Its eigh runs
     outside (``ingest.batch_left_vectors``)."""
-    blocks, _, _ = _repair_single(kind, d, m_pad, width, n_univ, method,
-                                  jax.random.fold_in(key, bidx), xs)
-    return lsvd.gram_stack(blocks, use_kernel=use_kernel).sum(axis=0)
+    with jax.named_scope("ranky.repair"):
+        blocks, _, _ = _repair_single(kind, d, m_pad, width, n_univ,
+                                      method, jax.random.fold_in(key, bidx),
+                                      xs)
+    with jax.named_scope("ranky.gram"):
+        return lsvd.gram_stack(blocks, use_kernel=use_kernel).sum(axis=0)
 
 
 def _step_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
@@ -301,13 +303,15 @@ def _step_single(kind: str, d: int, m_pad: int, width: int, n_univ: int,
                  decay: float, key, carry, xs):
     s, v, bidx, lonely_acc, repaired_acc = carry
     k_batch = jax.random.fold_in(key, bidx)
-    blocks, lonely_pb, repaired_b = _repair_single(
-        kind, d, m_pad, width, n_univ, method, k_batch, xs)
+    with jax.named_scope("ranky.repair"):
+        blocks, lonely_pb, repaired_b = _repair_single(
+            kind, d, m_pad, width, n_univ, method, k_batch, xs)
 
     if sk_rank is None:
         u_b = xs[-2]                       # (m_pad, r_b), from the gram's eigh
-        panel_b = ranky.right_vectors_stack(
-            blocks, u_b, jnp.ones((r_b,), jnp.float32))
+        with jax.named_scope("ranky.right"):
+            panel_b = ranky.right_vectors_stack(
+                blocks, u_b, jnp.ones((r_b,), jnp.float32))
     else:
         u_b, s_b, v_b = randomized.randomized_svd_blocks(
             blocks, rank=sk_rank, oversample=oversample,
@@ -621,19 +625,14 @@ def ingest_window(
     # Merge-phase fault seam: brackets the one compiled dispatch (a
     # raise cannot come from inside the scan's collectives).
     _fire_seam("ingest.merge")
-    if not obs.enabled():
-        carry, ys = fn(*call_args)
-    else:
-        # Compile-vs-execute split via the trace-count probe: the jit
-        # cache grows iff this window's shape had not been traced yet.
+    # Compile-vs-execute split via the trace-count probe: the jit cache
+    # grows iff this window's shape had not been traced yet.
+    with obs.span("ingest.window", bucket=str(sig), batches=t_len,
+                  backend=plan.backend) as args:
         pre_traces = fn._cache_size()
-        t0_us = clock.now_us()
         carry, ys = fn(*call_args)
-        compiled = fn._cache_size() > pre_traces
-        obs.trace.add_complete(
-            "ingest.window", t0_us, clock.now_us() - t0_us,
-            bucket=str(sig), batches=t_len, backend=plan.backend,
-            compiled=compiled)
+        compiled = args["compiled"] = fn._cache_size() > pre_traces
+    if obs.enabled():
         obs.counter_add("window_dispatch_total")
         if compiled:
             obs.counter_add("window_compile_total")
@@ -667,11 +666,15 @@ def ingest_window(
     # Fold the stacked small rotations into u AFTER the scan — u grows
     # with rows_seen and never rides in the carry.  Padded u_b rows are
     # sliced off with the host-side true row counts before they touch u.
+    # The fold runs eagerly, where no jax.named_scope reaches the ops'
+    # metadata, so it is named by a host span.
     u = state.u
-    for t in range(t_len):
-        uk_t = uk_stack[t]
-        ub_t = ub_stack[t, :true_m[t]]
-        u = jnp.concatenate([mm(u, uk_t[:k]), mm(ub_t, uk_t[k:])], axis=0)
+    with obs.span("stream.fold", batches=t_len):
+        for t in range(t_len):
+            uk_t = uk_stack[t]
+            ub_t = ub_stack[t, :true_m[t]]
+            u = jnp.concatenate([mm(u, uk_t[:k]), mm(ub_t, uk_t[k:])],
+                                axis=0)
 
     # The ONE host materialization of the window: the side-band counters
     # lived on device the whole way (no per-batch sync).

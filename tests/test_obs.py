@@ -1,5 +1,8 @@
 """The observability layer (repro.obs): span nesting/ordering and the
-ring buffer's drop-oldest overflow policy, the Prometheus/JSON metric
+ring buffer's drop-oldest overflow policy, spans on the profiler's host
+trace (the front door's svd.* spans with their args, obs on or off),
+the off path that touches no recorder, the named scopes in the solve
+program's lowered text, the Prometheus/JSON metric
 exporters (golden output), the plan-vs-measured drift monitor (fires a
 one-shot DriftWarning on an under-priced plan, stays silent for
 R5/R6/R7 at reference shapes), the disabled-mode contract (zero extra
@@ -15,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import obs
-from repro.core import planner
+from repro.core import planner, ranky, sparse
 from repro.core.api import (ASpec, ServeTopKConfig, SolveConfig,
                             serve_init, serve_topk, svd, svd_init,
                             svd_stream, svd_update)
@@ -86,8 +89,15 @@ def test_ring_overflow_drops_oldest(obs_on):
         # drop-OLDEST: the survivors are the most recent four
         assert [dict(e.args)["i"] for e in evs] == [6, 7, 8, 9]
         assert obs.trace.dropped() == 6
+        # the appended count keeps growing past capacity; since() reads
+        # what the ring still holds of the events after a count
+        assert obs.trace.appended() == 10
+        assert [dict(e.args)["i"] for e in obs.trace.since(8)] == [8, 9]
+        assert obs.trace.since(0) == evs
+        assert obs.trace.since(10) == []
         obs.trace.clear()
         assert obs.trace.events() == [] and obs.trace.dropped() == 0
+        assert obs.trace.appended() == 0
     finally:
         obs.trace.set_capacity(obs.gate.ring_capacity())
 
@@ -106,6 +116,154 @@ def test_chrome_trace_schema_roundtrip(obs_on):
     assert recs[0]["ph"] == "M"      # process_name metadata
     cats = {r.get("cat") for r in recs[1:]}
     assert cats == {"ingest", "snapshot"}
+
+
+def _coo(m=24, n=64, nnz=120, seed=0):
+    rng = np.random.default_rng(seed)
+    rows = rng.integers(0, m, nnz).astype(np.int32)
+    cols = rng.integers(0, n, nnz).astype(np.int32)
+    vals = rng.uniform(0.5, 2.0, nnz).astype(np.float32)
+    return sparse.COOMatrix(rows=rows, cols=cols, vals=vals, shape=(m, n))
+
+
+def _host_events(tmp_path, body):
+    """Run ``body`` under a profiler session; the host plane's events as
+    (name, start_ns, end_ns, stats dict)."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return out
+
+
+FRONT_DOOR = ("svd.plan", "svd.convert", "svd.solve", "svd.wait",
+              "svd.diagnostics")
+
+
+def test_front_door_spans_reach_the_profiler_with_obs_off(tmp_path):
+    """obs off, a profiler session on: one svd call leaves its five spans
+    on the host plane, one after the other inside the caller's
+    annotation, with their counts as stats; the ring stays empty."""
+    assert not obs.enabled()
+    obs.reset()
+    coo = _coo()
+    cfg = SolveConfig(num_blocks=4, method="neighbor_random",
+                      want_right=True)
+    svd(coo, cfg)                       # compile outside the session
+    got = {}
+
+    def call():
+        with jax.profiler.TraceAnnotation("caller"):
+            got["res"] = svd(coo, cfg)
+
+    evs = _host_events(tmp_path, call)
+    assert obs.trace.events() == []
+    (outer,) = [e for e in evs if e[0] == "caller"]
+    spans = []
+    for name in FRONT_DOOR:
+        (ev,) = [e for e in evs if e[0] == name]
+        assert outer[1] <= ev[1] <= ev[2] <= outer[2], name
+        spans.append(ev)
+    for before, after in zip(spans, spans[1:]):
+        assert before[2] <= after[1], (before[0], after[0])
+    stats = {e[0]: e[3] for e in spans}
+    ell = sparse.block_ell_from_coo(coo, 4)
+    assert stats["svd.convert"] == {"nnz": coo.nnz,
+                                    "ell_slots": int(ell.col_vals.size)}
+    diag = got["res"].diagnostics
+    assert stats["svd.diagnostics"] == {
+        "lonely_rows": diag.lonely_rows,
+        "repaired_rows": diag.repaired_rows}
+    assert stats["svd.solve"]["backend"] == "single"
+
+
+def test_span_args_added_in_the_body_reach_the_profiler(tmp_path):
+    def body():
+        with obs.span("late.flag", batches=3) as args:
+            args["compiled"] = True
+        obs.event("mark.here", v=2)
+
+    evs = {e[0]: e[3] for e in _host_events(tmp_path, body)}
+    assert evs["late.flag"] == {"batches": 3, "compiled": 1}
+    assert evs["mark.here"] == {"v": 2}
+
+
+def test_span_off_path_touches_no_recorder(monkeypatch):
+    """obs off, no profiler session: a span and an event ask the gate and
+    the profiler's is_enabled, and nothing else — no annotation, no
+    trace-state query, no jax trace or compile, no ring write."""
+    from repro.obs import clock
+
+    class NoProfiler:
+        @staticmethod
+        def is_enabled():
+            return False
+
+        def __init__(self, *a, **k):
+            raise AssertionError("annotation made with no session")
+
+    def no_query():
+        raise AssertionError("trace state queried on the off path")
+
+    assert not obs.enabled()
+    obs.reset()
+    monkeypatch.setattr(obs.trace, "TraceAnnotation", NoProfiler)
+    monkeypatch.setattr(obs.trace, "trace_state_clean", no_query)
+    clock.install_compile_probe()
+    c0 = clock.compile_seconds()
+    with obs.span("off.span", a=1) as args:
+        args["b"] = 2
+    obs.event("off.event")
+    assert clock.compile_seconds() == c0
+    assert obs.trace.events() == [] and obs.trace.appended() == 0
+
+
+def test_solve_single_lowered_text_carries_the_scopes():
+    ell = sparse.block_ell_from_coo(_coo(), 4)
+    text = ranky.solve_single.lower(
+        ell, num_blocks=4, method="neighbor_random", merge_mode="gram",
+        want_right=True).as_text(debug_info=True)
+    for scope in ("ranky.repair", "ranky.gram", "ranky.eigh",
+                  "ranky.right"):
+        assert scope in text, scope
+
+
+def test_ingest_window_span_carries_the_compile_flag(obs_on):
+    sw.clear_caches()
+    for seed in (7, 8):
+        svd_stream(iter(_batches(6, seed=seed)), CFG)
+    flags = [dict(e.args)["compiled"] for e in obs.trace.events()
+             if e.name == "ingest.window"]
+    assert flags[0] is True and flags[-1] is False
+
+
+def test_call_digest_survives_a_full_ring(obs_on):
+    """The ring is full (drop-oldest) before the call: the call's own
+    spans still make its Diagnostics.span_summary."""
+    try:
+        obs.trace.set_capacity(16)
+        for i in range(40):
+            obs.event("ring.fill", i=i)
+        assert obs.trace.dropped() == 24
+        rng = np.random.default_rng(0)
+        a = rng.standard_normal((16, 32)).astype(np.float32)
+        summary = svd(a, SolveConfig(num_blocks=2)).diagnostics.span_summary
+        assert {row[0] for row in summary} == {
+            "svd.plan", "svd.convert", "svd.solve", "svd.wait"}
+    finally:
+        obs.trace.set_capacity(obs.gate.ring_capacity())
 
 
 # ---------------------------------------------------------------------------
