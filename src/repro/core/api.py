@@ -700,6 +700,9 @@ def svd(a: MatrixInput, config: Optional[SolveConfig] = None, *,
         else:  # pragma: no cover - planner only emits the three above
             raise AssertionError(
                 f"planner produced unknown backend {p.backend!r}")
+        # Dispatched behind the solve: its copy and launch overlap the
+        # solve on the device; svd.diagnostics only reads it back.
+        lonely_dev = ranky.lonely_counts(a_norm, d)
 
     with obs.span("svd.wait"):
         u, s = out[0], out[1]
@@ -714,7 +717,7 @@ def svd(a: MatrixInput, config: Optional[SolveConfig] = None, *,
     timing = timer.finish()
 
     with obs.span("svd.diagnostics") as args:
-        lonely = ranky.lonely_rows_per_block(a_norm, d)
+        lonely = tuple(int(x) for x in np.asarray(lonely_dev))
         lonely_total = sum(lonely)
         repaired = _repaired_rows(a_norm, d, config.method,
                                   config.resolved_key(), lonely_total,

@@ -172,19 +172,33 @@ def sparse_lonely_rows(
     return sparse_row_counts(col_rows, col_vals, m) == 0
 
 
-def lonely_rows_per_block(a_norm, num_blocks: int) -> Tuple[int, ...]:
+@partial(jax.jit, static_argnames="m")
+def _ell_lonely_counts(col_rows: jnp.ndarray, col_vals: jnp.ndarray,
+                       m: int) -> jnp.ndarray:
+    """(D,) lonely-row counts of a BlockEll's stacked index arrays."""
+    lonely = jax.vmap(partial(sparse_lonely_rows, m=m))(col_rows, col_vals)
+    return lonely.sum(axis=1, dtype=jnp.int32)
+
+
+def lonely_counts(a_norm, num_blocks: int):
     """Per-block lonely-row counts of a normalized input — dense
-    (M, N_pad) array (N_pad divisible by num_blocks) or BlockEll.  The
-    shared diagnostics helper behind ``api.svd`` and ``stream.ingest``
-    (host-side tuple of ints)."""
+    (M, N_pad) array (N_pad divisible by num_blocks) or BlockEll — as a
+    (D,) array.  For a BlockEll this dispatches one compiled program
+    (cached on shapes and M) and returns without waiting, so a caller
+    can queue it behind other device work and read it back later."""
     if isinstance(a_norm, sparse.BlockEll):
-        lonely = jax.vmap(
-            lambda rows, vals: sparse_lonely_rows(rows, vals, a_norm.m)
-        )(a_norm.col_rows, a_norm.col_vals)
-        return tuple(int(x) for x in np.asarray(lonely.sum(axis=1)))
+        return _ell_lonely_counts(a_norm.col_rows, a_norm.col_vals,
+                                  m=a_norm.m)
     m, n = a_norm.shape
     blocks = np.asarray(a_norm).reshape(m, num_blocks, n // num_blocks)
-    return tuple(int(x) for x in (~(blocks != 0).any(axis=2)).sum(axis=0))
+    return (~(blocks != 0).any(axis=2)).sum(axis=0)
+
+
+def lonely_rows_per_block(a_norm, num_blocks: int) -> Tuple[int, ...]:
+    """:func:`lonely_counts` read back as a host-side tuple of ints — the
+    shared diagnostics helper behind ``api.svd`` and ``stream.ingest``."""
+    return tuple(int(x) for x in np.asarray(lonely_counts(a_norm,
+                                                          num_blocks)))
 
 
 def row_adjacency_sparse(ell: "sparse.BlockEll") -> jnp.ndarray:

@@ -1,3 +1,4 @@
+import contextlib
 import os
 import subprocess
 import sys
@@ -23,6 +24,27 @@ def pytest_configure(config):
             "markers",
             "timeout(seconds): per-test hard timeout, enforced by "
             "pytest-timeout when installed (no-op without it)")
+
+
+@contextlib.contextmanager
+def jaxpr_traces():
+    """Count the jaxpr traces JAX makes inside the block: the
+    ``/jax/core/compile/jaxpr_trace_duration`` events that
+    ``bench/run.py``'s ``CompileCounter`` counts.  Yields a one-item list
+    holding the count."""
+    import jax.monitoring as mon
+
+    seen = [0]
+
+    def on(event, secs, **kw):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            seen[0] += 1
+
+    mon.register_event_duration_secs_listener(on)
+    try:
+        yield seen
+    finally:
+        mon.unregister_event_duration_listener(on)
 
 
 def run_forced_devices(body: str, devices: int = 8) -> str:

@@ -17,6 +17,7 @@ from repro.core.api import (SolveConfig, SVDResult, as_block_input,
                             default_key, describe, plan, svd)
 from repro.core.hierarchy import hierarchical_ranky_svd
 from repro.core.planner import ASpec, PlanError
+from conftest import jaxpr_traces
 from repro.core.ranky import ranky_svd
 
 
@@ -392,6 +393,32 @@ def test_result_diagnostics_and_unpacking():
     u, s = res
     _bitwise(u, res.u)
     _bitwise(s, res.s)
+
+
+@pytest.mark.parametrize("as_ell", [False, True], ids=["coo", "ell"])
+def test_svd_lonely_counts_match_host_count_without_retrace(as_ell):
+    """api.svd of a COO (or of its BlockEll) counts lonely (block, row)
+    pairs as a host bincount over the COO does, repairs each of them
+    under neighbor_random, and a second call with the same shapes runs no
+    jaxpr trace (every program, the count's included, is reused)."""
+    coo = _coo(m=32, n=1024, density=0.01, seed=3)
+    d, (m, n) = 8, coo.shape
+    a = sparse.block_ell_from_coo(coo, d) if as_ell else coo
+    cfg = SolveConfig(backend="single", method="neighbor_random",
+                      num_blocks=d, merge_mode="gram")
+    first = svd(a, cfg)
+    blk = coo.cols // sparse.block_width(n, d)
+    hits = np.bincount(blk * m + coo.rows, weights=coo.vals != 0,
+                       minlength=d * m).reshape(d, m)
+    want = tuple(int(x) for x in (hits == 0).sum(axis=1))
+    assert sum(c > 0 for c in want) >= 2
+    with jaxpr_traces() as traces:
+        res = svd(a, cfg)
+    assert traces[0] == 0
+    for diag in (first.diagnostics, res.diagnostics):
+        assert diag.lonely_rows_per_block == want
+        assert diag.lonely_rows == sum(want)
+        assert diag.repaired_rows == diag.lonely_rows
 
 
 def test_diagnostics_neighbor_counts_partial_repairs():

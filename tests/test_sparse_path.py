@@ -10,6 +10,7 @@ import pytest
 from repro.core import ranky, sparse
 from repro.core import svd as lsvd
 from repro.core.hierarchy import hierarchical_ranky_svd
+from conftest import jaxpr_traces
 
 KEY = jax.random.PRNGKey(0)
 
@@ -104,6 +105,31 @@ def test_sparse_lonely_and_adjacency_match_dense():
     np.testing.assert_array_equal(
         np.asarray(ranky.row_adjacency_sparse(ell)),
         np.asarray(ranky.row_adjacency(jnp.asarray(a))))
+
+
+@pytest.mark.parametrize("on_device", [False, True], ids=["numpy", "device"])
+@pytest.mark.parametrize("num_blocks", [2, 8])
+def test_lonely_counts_match_reference_without_retrace(num_blocks, on_device):
+    """The per-block lonely counts of a BlockEll (numpy- or device-backed)
+    equal the literal reference and the dense path; a second call with
+    the same shapes reuses the compiled program (no jaxpr trace)."""
+    coo = _coo()
+    a = sparse.pad_to_block_multiple(coo.todense(), num_blocks)
+    want = tuple(int(ranky.ref_lonely_rows(b).sum())
+                 for b in _dense_blocks(a, num_blocks))
+    assert sum(c > 0 for c in want) >= 2  # lonely rows in several blocks
+    ell = sparse.block_ell_from_coo(coo, num_blocks)
+    if on_device:
+        ell = jax.device_put(ell)
+        assert isinstance(ell.col_rows, jax.Array)
+    assert ranky.lonely_rows_per_block(ell, num_blocks) == want
+    assert ranky.lonely_rows_per_block(a, num_blocks) == want
+    with jaxpr_traces() as traces:
+        counts = ranky.lonely_counts(ell, num_blocks)
+        again = ranky.lonely_rows_per_block(ell, num_blocks)
+    assert traces[0] == 0
+    assert counts.shape == (num_blocks,)
+    assert tuple(int(x) for x in np.asarray(counts)) == again == want
 
 
 @pytest.mark.parametrize("method", ["random", "neighbor", "neighbor_random"])
