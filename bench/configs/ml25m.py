@@ -10,7 +10,9 @@ Nothing here imports the program.
   and cut to rank k; the left factor becomes [U W_old ; U_b W_new].
 * :func:`state_numbers` compares two rank-k states X = U diag(s) V^T by
   ||X - X_ref||_F / ||X_ref||_F, formed from k x k products only
-  (X itself has rows_seen x items entries).
+  (X itself has rows_seen x items entries); :func:`lead_numbers` does
+  the same for their leading parts, cut where the reference's singular
+  values fall by a tenth or more.
 * :func:`topk_numbers` checks served top-k ids and scores against a
   float64 top-k over the served factors, tie-aware.
 """
@@ -264,6 +266,29 @@ def state_numbers(u, s, v, ref) -> dict:
     yy = inner(u_r, s_r, v_r, u_r, s_r, v_r)
     xy = inner(u, s, v, u_r, s_r, v_r)
     return {"state": float(np.sqrt(max(xx + yy - 2 * xy, 0.0) / yy))}
+
+
+# The leading part of a state ends where the reference's singular values
+# fall by this share or more.  Its product is then set by the data to
+# within rounding over that gap, whereas the rank-k cut of every fold
+# falls among nearly equal values (the 40th and 41st eigenvalues of a
+# batch gram, the 32nd and 33rd singular values of a merged panel), where
+# float32 rounding can keep the other of two directions.
+LEAD_GAP = 0.1
+
+
+def lead_numbers(u, s, v, ref) -> dict:
+    """``lead``: :func:`state_numbers` of the leading r singular triplets
+    of both states, r the last place before which the reference's
+    singular values fall by ``LEAD_GAP`` or more (all of them where they
+    never do); ``lead_rank``: that r."""
+    s_r = np.asarray(ref[1], np.float64)
+    drop = np.nonzero((s_r[:-1] - s_r[1:]) >= LEAD_GAP * s_r[:-1])[0]
+    r = int(drop[-1]) + 1 if drop.size else s_r.size
+    cut = [(np.asarray(x)[:, :r], np.asarray(y)[:r], np.asarray(z)[:, :r])
+           for x, y, z in ((u, s, v), ref)]
+    return {"lead": state_numbers(*cut[0], cut[1])["state"],
+            "lead_rank": r}
 
 
 # Ties: an item must be returned when its float64 score beats the k-th

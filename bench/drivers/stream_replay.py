@@ -15,9 +15,10 @@ the same rows into the same state, so the shapes repeat and the state
 keeps its ``rows_seen``.
 
 The check folds the history and then the replay in float64 from the
-empty state (the configuration's reference), and holds both the
-program's ``state0`` (``history``) and one replay of the window
-(``state``) against it.
+empty state (the configuration's reference), and holds the leading part
+of both the program's ``state0`` (``history_lead``) and one replay of
+the window (``state_lead``) against it (the configuration's
+``lead_numbers``).
 """
 from __future__ import annotations
 
@@ -95,8 +96,8 @@ def check(ctx: dict, win: dict) -> dict:
     ref0 = cell.ref.fold(*cell.ref.empty_state(int(cell.config["items"])),
                          ctx["history"], k=k, oversample=p)
     ref = cell.ref.fold(*ref0, ctx["replay"], k=k, oversample=p)
-    nums = {"history": cell.ref.state_numbers(*got0, ref0)["state"],
-            "state": cell.ref.state_numbers(*got, ref)["state"],
+    nums = {"history_lead": cell.ref.lead_numbers(*got0, ref0)["lead"],
+            "state_lead": cell.ref.lead_numbers(*got, ref)["lead"],
             "rows": abs(rows_seen - ref[0].shape[0]),
             "repairs": repaired}
     return harness.checks(nums, cell.traffic["limits"])

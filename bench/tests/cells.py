@@ -21,20 +21,16 @@ from bench import run  # noqa: E402
 
 SEED = 2 ** 31 + 977       # larger than 32 signed bits hold
 
-# The streaming and serving mixes are kept, tested, for later cells:
-# BENCHMARK.json does not list them yet (PERF.md, Open questions).
-KEPT = {"workloads": [{"name": "ml25m-ingest", "config": "ml25m",
-                       "traffic": "replay-8x2048", "chips": 1},
-                      {"name": "ml25m-serve", "config": "ml25m",
+# The serving mix is kept, tested, for a later cell: BENCHMARK.json does
+# not list it yet (PERF.md, Open questions).
+KEPT = {"workloads": [{"name": "ml25m-serve", "config": "ml25m",
                        "traffic": "topk-poisson", "chips": 1}],
-        "end_to_end": [{"name": "ingest_rows_per_s", "unit": "rows/s",
-                        "workloads": ["ml25m-ingest"]},
-                       {"name": "serve_p95_ms", "unit": "ms",
+        "end_to_end": [{"name": "serve_p95_ms", "unit": "ms",
                         "workloads": ["ml25m-serve"]}]}
 
 
 def spec() -> dict:
-    """``BENCHMARK.json`` with the kept mixes' cells added."""
+    """``BENCHMARK.json`` with the kept mix's cell added."""
     out = run.load_spec()
     for key, extra in KEPT.items():
         out[key] = out[key] + extra

@@ -72,6 +72,18 @@ def _state_unchanged(monkeypatch):
     monkeypatch.setattr(api, "svd_stream", unchanged)
 
 
+def _alter_state(monkeypatch):
+    real = api.svd_stream
+
+    def altered(batches, config=None, *, state=None, **kw):
+        res = real(batches, config, state=state, **kw)
+        st = res.state
+        return dataclasses.replace(res, state=dataclasses.replace(
+            st, s=st.s.at[0].multiply(1.001)))
+
+    monkeypatch.setattr(api, "svd_stream", altered)
+
+
 def _half_batch(monkeypatch):
     real = api.svd_stream
 
@@ -102,6 +114,7 @@ FAULTS = {
     "paper-oneshot/half the matrix": ("paper-oneshot", _half_matrix, False),
     "ml25m-ingest/state unchanged": ("ml25m-ingest", _state_unchanged, True),
     "ml25m-ingest/half of each batch": ("ml25m-ingest", _half_batch, True),
+    "ml25m-ingest/answer altered": ("ml25m-ingest", _alter_state, True),
     "ml25m-serve/answer altered": ("ml25m-serve", _alter_id, True),
 }
 
@@ -135,7 +148,7 @@ def test_fault_makes_the_run_not_correct(fault, monkeypatch):
 # --- the control: the reference in the program's place, at HIGH ----------
 
 # The number of each cell that separates the control from the program.
-SEPARATES = {"paper-oneshot": "recon", "ml25m-ingest": "history",
+SEPARATES = {"paper-oneshot": "recon", "ml25m-ingest": "history_lead",
              "ml25m-serve": "score"}
 
 

@@ -35,6 +35,19 @@ def test_every_cell_finds_its_files():
         assert "setup_s" in names and len(names) >= 2
 
 
+def test_every_per_layer_metric_names_a_reader_and_cells_that_exist():
+    spec = run.load_spec()
+    cell_names = {w["name"] for w in spec["workloads"]}
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    for m in spec["per_layer"]:
+        assert callable(run.reader_module(m["name"]).read), m["name"]
+        assert m["moves"] in e2e, m["name"]
+        for w in m.get("workloads", []):
+            assert w in cell_names, (m["name"], w)
+            # The cell reports the end-to-end metric this one moves.
+            assert w in e2e[m["moves"]].get("workloads", [w]), (m["name"], w)
+
+
 def test_every_config_file_is_listed_with_its_cuts():
     spec = run.load_spec()
     for c in spec["configs"]:
